@@ -17,6 +17,14 @@ with examples):
                           re-traces — the retrace-storm bug class), or
                           whose nested kernel closes over a name that is
                           not part of its cache key.
+  program-unnamed         a bare ``jax.jit`` (call, decorator or
+                          ``functools.partial(jax.jit, …)``) inside a
+                          ``@kernel_factory`` factory or in the program
+                          handed to a ``_cache_put`` program cache: its
+                          program would run in the device trace under
+                          the traced function's own name (``kernel``),
+                          unreadable by factory — build it with
+                          ``observe.compile.named_jit``.
   jit-in-loop             ``jax.jit``/``jax.pmap`` called inside a
                           ``for``/``while`` body.
   raw-float64-literal     ``jnp.{float64,int64,uint64,complex128}``
@@ -138,6 +146,7 @@ __all__ = ["Finding", "RULES", "lint_source", "lint_paths", "main"]
 RULES = (
     "implicit-host-sync",
     "kernel-factory-unkeyed",
+    "program-unnamed",
     "jit-in-loop",
     "fault-point-not-in-catalogue",
     "raw-float64-literal",
@@ -304,6 +313,7 @@ class _Linter(ast.NodeVisitor):
             self._check_module_mutables(tree, _lockcheck)
         self.visit(tree)
         self._check_factories(tree)
+        self._check_unnamed_programs(tree)
         self._check_unlowered(tree)
         return [f for f in self.findings if not self._suppressed(f)]
 
@@ -904,7 +914,7 @@ class _Linter(ast.NodeVisitor):
                 continue
             builds = [n for n in ast.walk(node) if isinstance(n, ast.Call)
                       and _dotted(n.func) in ("jax.jit", "jit", "shard_map",
-                                              "jax.shard_map")]
+                                              "jax.shard_map", "named_jit")]
             if not builds:
                 continue
             deco_exprs = [d.func if isinstance(d, ast.Call) else d
@@ -940,6 +950,58 @@ class _Linter(ast.NodeVisitor):
                                "is not part of the factory's cache key — "
                                "thread it through the (hashable) factory "
                                "arguments", def_line_only=True)
+
+
+    # -- program-unnamed -----------------------------------------------------
+
+    def _check_unnamed_programs(self, tree: ast.Module) -> None:
+        """Every program a kernel factory or the ``_cache_put`` program
+        cache builds goes through ``named_jit``, so the device trace
+        names it by factory (observe/compile.py)."""
+        scopes = []
+        for node in ast.walk(tree):
+            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)) \
+                    and any((_dotted(d.func if isinstance(d, ast.Call)
+                                     else d) or "").rsplit(".", 1)[-1]
+                            == "kernel_factory"
+                            for d in node.decorator_list):
+                scopes.append((node, f"kernel factory {node.name!r}"))
+            elif isinstance(node, ast.Call) and (
+                    _dotted(node.func) or "").rsplit(".", 1)[-1] \
+                    == "_cache_put":
+                scopes.append((node, "the _cache_put program cache"))
+        seen = set()
+        for scope, where in scopes:
+            for n in ast.walk(scope):
+                bare = _bare_jit(n)
+                if bare is None or id(bare) in seen:
+                    continue
+                seen.add(id(bare))
+                self._emit(bare, "program-unnamed",
+                           f"bare jax.jit in {where}: the program runs "
+                           "in the device trace as 'jit_<traced "
+                           "function>' — build it with "
+                           "observe.compile.named_jit so the trace names "
+                           "it by factory")
+
+
+def _bare_jit(node: ast.AST) -> Optional[ast.AST]:
+    """The ``jax.jit`` reference that ``node`` builds a program with —
+    a ``jax.jit(...)`` call, a ``functools.partial(jax.jit, ...)``, or a
+    bare ``@jax.jit`` decorator — else None."""
+    jits = ("jax.jit", "jit")
+    if isinstance(node, ast.Call):
+        target = _dotted(node.func)
+        if target in jits:
+            return node
+        if (target or "").rsplit(".", 1)[-1] == "partial" and node.args \
+                and _dotted(node.args[0]) in jits:
+            return node
+    if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+        for d in node.decorator_list:
+            if _dotted(d) in jits:
+                return d
+    return None
 
 
 _INSTRUMENT_DECOS = ("plan_check.instrument", "instrument")

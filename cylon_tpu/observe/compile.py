@@ -1,4 +1,4 @@
-"""Compilation observability — make every jit build a measured event.
+"""Compilation observability — stable program names and measured builds.
 
 The engine's kernel factories (``shuffle._exchange_fn`` and friends)
 are ``lru_cache``-keyed builders of jit/shard_map programs: a factory
@@ -8,23 +8,28 @@ denominator (docs/tpu_perf_notes.md "the per-query floor"): ROADMAP §5's
 q11-at-0.23x number is meaningless until compile time, retrace storms
 and kernel time can be told apart.  This module is the instrument:
 
+  * :func:`named_jit` — ``jax.jit`` under a stable, readable program
+    name derived from the factory that builds it (``shuffle._exchange_fn``
+    compiles as ``jit(shuffle__exchange_fn)`` and runs in the profiler
+    trace as module ``jit_shuffle__exchange_fn``), so device time can be
+    read by factory.  The name is fixed when the program is built; a
+    dispatch pays nothing for it.  Every factory and hand-rolled program
+    cache builds through it (graftlint ``program-unnamed``).
+
   * :func:`kernel_factory` — a drop-in replacement for
     ``functools.lru_cache(maxsize=None)`` on kernel factories.  Factory
-    hits/misses tally ``compile.cache_hits`` / ``compile.cache_misses``;
-    the first CONCRETE call of each new shape signature through a built
-    kernel is timed as a build event — ``compile.builds`` +
-    ``compile.build_us`` counters, a ``compile.build`` span whose args
-    carry the factory name, cache key, trace-ms and compile-ms — and
-    attributed to the active per-query collector (the serving layer and
-    EXPLAIN ANALYZE each open one, so ``QueryHandle.compile_ms`` and
-    ``report.totals["compile_ms"]`` are exact, not inferred).
+    hits/misses tally ``compile.cache_hits`` / ``compile.cache_misses``,
+    and the factory names the programs it builds.
 
-    Timing honesty: jit dispatch is async, so the first call's wall
-    clock is trace + lowering + XLA compile + enqueue — no device
-    execution rides in it.  The pure tracing share is measured
-    separately via one ``jax.eval_shape`` pre-pass (``compile.trace_us``)
-    and ONLY while counters are enabled — plain production dispatch
-    never pays the extra abstract trace.
+  * compiles counted where they happen: one ``jax.monitoring`` listener
+    on JAX's backend-compile event counts EVERY XLA compile (or
+    persistent-cache load) — ``compile.builds`` + ``compile.build_us``,
+    a ``compile.build`` span whose args carry the program name and
+    compile-ms — and attributes it to the thread's active per-query
+    collector (the serving layer and EXPLAIN ANALYZE each open one, so
+    ``QueryHandle.compile_ms`` and ``report.totals["compile_ms"]`` are
+    exact, not inferred).  Abstract plan runs (analysis/plan_check)
+    compile nothing, so they count nothing.
 
   * the **recompile-storm detector**: each factory keeps a sliding
     window of recent distinct cache keys; when one factory builds
@@ -34,16 +39,13 @@ and kernel time can be told apart.  This module is the instrument:
     ``compile.storms`` tallies.  A shuffle whose size classes thrash,
     or a predicate rebuilt per call defeating the select cache, becomes
     one loud line instead of a mystery wall-clock tax.
-
-Abstract plan runs (analysis/plan_check): calls whose leaves are
-tracers build nothing on the device and are passed straight through —
-measuring them would charge abstract-interpretation time to "compile".
 """
 from __future__ import annotations
 
 import functools
 import inspect
 import os
+import re
 import threading
 import time
 from collections import deque
@@ -52,9 +54,9 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from .locks import OrderedLock
 
-__all__ = ["kernel_factory", "attribute_compiles", "note_build",
-           "clear_state", "serial_call", "enable_compile_cache",
-           "STORM_KEYS", "STORM_WINDOW_S"]
+__all__ = ["kernel_factory", "named_jit", "program_names",
+           "attribute_compiles", "note_build", "clear_state", "serial_call",
+           "enable_compile_cache", "STORM_KEYS", "STORM_WINDOW_S"]
 
 # the storm detector's window: this many DISTINCT cache keys built by
 # one factory within this many seconds is a retrace storm worth a warn
@@ -109,11 +111,12 @@ _tls = threading.local()
 
 @contextmanager
 def attribute_compiles():
-    """Collect every build event on this thread inside the block; yields
-    the (live) list of ``{"factory", "key", "compile_ms", "trace_ms"}``
-    dicts.  Nests like ``stats.collect_digests``: the innermost
-    collector owns the events of its extent.  Zero overhead per build
-    when no collector is open beyond one thread-local read."""
+    """Collect every XLA compile on this thread inside the block; yields
+    the (live) list of ``{"program", "compile_ms"}`` dicts.  Nests like
+    ``stats.collect_digests``: the innermost collector owns the events
+    of its extent.  Zero overhead per compile when no collector is open
+    beyond one thread-local read."""
+    _ensure_listener()
     stack = getattr(_tls, "collectors", None)
     if stack is None:
         stack = _tls.collectors = []
@@ -125,21 +128,102 @@ def attribute_compiles():
         stack.pop()
 
 
-def _attribute(event: Dict[str, Any]) -> None:
-    stack = getattr(_tls, "collectors", None)
-    if stack:
-        stack[-1].append(event)
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
-def _observing() -> bool:
-    """Is anyone watching builds right now — counters on, or a
-    per-query collector open on this thread?  The kernel handles use
-    this as their fast-path gate: unobserved production dispatch must
-    cost a couple of attribute reads, not a pytree flatten per call."""
+def _on_compile(event: str, duration: float, **kw) -> None:
+    """The ``jax.monitoring`` listener: one XLA compile (or persistent
+    cache load) just finished on this thread.  JAX calls it on the
+    thread that compiles, which is the thread that dispatched."""
+    if event != BACKEND_COMPILE_EVENT:
+        return
     from .. import trace
-    if trace.counters_enabled():
-        return True
-    return bool(getattr(_tls, "collectors", None))
+    stack = getattr(_tls, "collectors", None)
+    if not stack and not trace.counters_enabled():
+        return
+    ms = duration * 1e3
+    program = kw.get("fun_name")
+    trace.count("compile.builds")
+    trace.count("compile.build_us", int(round(ms * 1e3)))
+    trace.record_span("compile.build", time.perf_counter() - duration, ms,
+                      args={"program": program,
+                            "compile_ms": round(ms, 3)})
+    if stack:
+        stack[-1].append({"program": program, "compile_ms": ms})
+
+
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def _ensure_listener() -> None:
+    """Register :func:`_on_compile` with ``jax.monitoring``, once per
+    process."""
+    global _listening
+    if _listening:
+        return
+    with _listener_lock:
+        if _listening:
+            return
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _listening = True
+
+
+# ---------------------------------------------------------------------------
+# stable program names
+# ---------------------------------------------------------------------------
+
+# label -> origin ("module.factory") of every program named so far: a
+# label names one origin, so the device trace's module names say which
+# factory built each program
+_program_origins: Dict[str, str] = {}
+_names_lock = threading.Lock()
+
+
+def _label(origin: str) -> str:
+    """``"shuffle._exchange_fn"`` -> ``"shuffle__exchange_fn"``: the
+    jitted function's name, which XLA's module name carries."""
+    return re.sub(r"[^A-Za-z0-9_]", "_", origin)
+
+
+def program_names() -> Dict[str, str]:
+    """{program label: origin} of every program :func:`named_jit` has
+    named in this process."""
+    with _names_lock:
+        return dict(_program_origins)
+
+
+def named_jit(fn, name: Optional[str] = None, **jit_kwargs):
+    """``jax.jit(fn, **jit_kwargs)`` under a stable program name.
+
+    ``name`` is ``"<module>.<function>"`` of the code that builds the
+    program; inside a :func:`kernel_factory` build it defaults to the
+    factory's own (``"shuffle._exchange_fn"``).  The traced function is
+    renamed before jit wraps it, so the name is fixed at build time and
+    a dispatch (jit's C++ fast path) never calls back into it.  One name
+    belongs to one origin: a second origin under the same label raises.
+    """
+    if name is None:
+        building = getattr(_tls, "building", None)
+        if not building:
+            raise ValueError("named_jit outside a kernel_factory build "
+                             "needs name='<module>.<function>'")
+        name = building[-1]
+    label = _label(name)
+    with _names_lock:
+        seen = _program_origins.setdefault(label, name)
+    if seen != name:
+        raise ValueError(f"program name {label!r} is taken by {seen!r}; "
+                         f"{name!r} needs another")
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = label
+    import jax
+    return jax.jit(program, **jit_kwargs)
 
 
 # ---------------------------------------------------------------------------
@@ -149,7 +233,9 @@ def _observing() -> bool:
 # Lint contract (graftlint shared-state-unguarded,
 # docs/static_analysis.md "Concurrency discipline"): writes to these
 # module registries hold the mapped lock.
-GUARDED_STATE = {"_recent_keys": "_storm_lock"}
+GUARDED_STATE = {"_recent_keys": "_storm_lock",
+                 "_program_origins": "_names_lock",
+                 "_listening": "_listener_lock"}
 
 _storm_lock = OrderedLock("compile.storm")
 _recent_keys: Dict[str, deque] = {}   # factory -> deque[(t, key)]
@@ -289,100 +375,35 @@ def serial_call(fn, args, kwargs):
 
 
 # ---------------------------------------------------------------------------
-# the factory decorator + the per-kernel build timer
+# the factory decorator
 # ---------------------------------------------------------------------------
 
-def _signature(args, kwargs) -> Tuple:
-    """Hashable shape/dtype signature of one call — what jit's own cache
-    keys on, minus shardings (one factory key pins one mesh, so the
-    sharding axis cannot vary under it)."""
-    import jax
-    leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
-    sig: List[Any] = [treedef]
-    for lf in leaves:
-        shape = getattr(lf, "shape", None)
-        dtype = getattr(lf, "dtype", None)
-        if shape is not None and dtype is not None:
-            sig.append(("a", tuple(shape), str(dtype)))
-        else:
-            sig.append(("o", lf))
-    return tuple(sig)
-
-
 class _KernelHandle:
-    """Wraps one built kernel: transparent call-through, with the first
-    concrete call of each new shape signature timed as a build event."""
+    """Wraps one built kernel: transparent call-through, serialized on
+    the cpu backend (:func:`serial_call`)."""
 
-    __slots__ = ("_fn", "factory", "key", "_seen", "fresh")
+    __slots__ = ("_fn", "fresh")
 
-    def __init__(self, fn, factory: str, key: Tuple) -> None:
+    def __init__(self, fn) -> None:
         self._fn = fn
-        self.factory = factory
-        self.key = key
-        self._seen: set = set()
         self.fresh = True
 
-    def _dispatch(self, args, kwargs):
+    def __call__(self, *args, **kwargs):
         return serial_call(self._fn, args, kwargs)
 
-    def __call__(self, *args, **kwargs):
-        # fast-path gate: unobserved dispatch (counters off, no
-        # collector) goes straight to the kernel — no flatten, no
-        # signature.  A build that happens unobserved is simply not
-        # recorded (its counters would no-op anyway); when observation
-        # starts later, the first observed call of an already-compiled
-        # signature measures as a near-zero "build" — harmless noise
-        # vs. taxing every production dispatch
-        if not _observing():
-            return self._dispatch(args, kwargs)
-        from ..analysis._abstract import is_abstract
-        import jax
-        try:
-            leaves = jax.tree_util.tree_leaves((args, kwargs))
-            if any(is_abstract(lf) for lf in leaves):
-                # abstract plan run: nothing compiles on the device —
-                # charging eval_shape time to "compile" would be a lie
-                return self._fn(*args, **kwargs)
-            sig = _signature(args, kwargs)
-        except TypeError:
-            return self._dispatch(args, kwargs)  # unhashable leaf — skip
-        if sig in self._seen:
-            return self._dispatch(args, kwargs)
-        return self._build_call(sig, args, kwargs)
 
-    def _build_call(self, sig, args, kwargs):
-        from .. import trace
-        trace_ms: Optional[float] = None
-        if trace.counters_enabled():
-            # the pure tracing share, via one abstract pre-pass — only
-            # while someone is watching (production dispatch skips it)
-            try:
-                import jax
-                t0 = time.perf_counter()
-                jax.eval_shape(self._fn, *args, **kwargs)
-                trace_ms = (time.perf_counter() - t0) * 1e3
-            except Exception:  # graftlint: ok[broad-except] — the
-                trace_ms = None  # trace split is best-effort telemetry
-        t1 = time.perf_counter()
-        out = self._dispatch(args, kwargs)
-        build_ms = (time.perf_counter() - t1) * 1e3
-        # mark seen AFTER a successful dispatch: a failed first call
-        # must re-measure (and re-raise) next time, not go dark
-        self._seen.add(sig)
-        trace.count("compile.builds")
-        trace.count("compile.build_us", int(round(build_ms * 1e3)))
-        if trace_ms is not None:
-            trace.count("compile.trace_us", int(round(trace_ms * 1e3)))
-        trace.record_span(
-            "compile.build", t1, build_ms,
-            args={"factory": self.factory,
-                  "key": repr(self.key)[:_KEY_REPR_LEN],
-                  "trace_ms": (None if trace_ms is None
-                               else round(trace_ms, 3)),
-                  "compile_ms": round(build_ms, 3)})
-        _attribute({"factory": self.factory, "key": self.key,
-                    "compile_ms": build_ms, "trace_ms": trace_ms})
-        return out
+@contextmanager
+def _building_as(name: str):
+    """Name the programs :func:`named_jit` builds on this thread inside
+    the block after factory ``name`` (nested factory builds shadow)."""
+    stack = getattr(_tls, "building", None)
+    if stack is None:
+        stack = _tls.building = []
+    stack.append(name)
+    try:
+        yield
+    finally:
+        stack.pop()
 
 
 def kernel_factory(fn):
@@ -390,16 +411,24 @@ def kernel_factory(fn):
     compile observability (module docstring).  Drop-in: same positional
     hashable-args contract, ``cache_clear``/``cache_info`` preserved;
     graftlint's ``kernel-factory-unkeyed`` rule recognizes it as a cache
-    decorator."""
+    decorator.  Programs the factory builds with :func:`named_jit` are
+    named ``<module>.<factory>``."""
     factory = fn.__qualname__
+    name = f"{fn.__module__.rsplit('.', 1)[-1]}.{factory}"
     try:
         params = tuple(inspect.signature(fn).parameters)
     except (TypeError, ValueError):
         params = ()
+    _ensure_listener()
+
+    @functools.wraps(fn)
+    def build(*key):
+        with _building_as(name):
+            return fn(*key)
 
     @functools.lru_cache(maxsize=None)
     def _build(*key) -> _KernelHandle:
-        return _KernelHandle(fn(*key), factory, key)
+        return _KernelHandle(build(*key))
 
     @functools.wraps(fn)
     def wrapper(*key):
@@ -414,5 +443,6 @@ def kernel_factory(fn):
 
     wrapper.cache_clear = _build.cache_clear
     wrapper.cache_info = _build.cache_info
-    wrapper.__wrapped__ = fn
+    # the uncached factory call (programs still named after the factory)
+    wrapper.__wrapped__ = build
     return wrapper

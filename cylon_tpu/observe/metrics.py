@@ -156,6 +156,9 @@ METRICS: Dict[str, MetricSpec] = _specs(
     ("host.read", COUNTER, "reads",
      "batched device->host reads (count-protocol flushes, exports, "
      "optimistic-dispatch validations)"),
+    ("host.read_us", COUNTER, "us",
+     "host time blocked in the host.read transfers (the cylon.host.read "
+     "profiler spans); timed only while counters are enabled"),
     ("broadcast.replica_cache_size", GAUGE, "entries",
      "live entries in the broadcast replica cache"),
     # resilience (docs/robustness.md): budget guardrails, degraded
@@ -241,6 +244,10 @@ METRICS: Dict[str, MetricSpec] = _specs(
      "replayed; no rewrite, no strategy re-decision)"),
     ("plan.cache_miss", COUNTER, "misses",
      "materializations that rewrote + compiled a fresh plan"),
+    ("plan.host_us", COUNTER, "us",
+     "host time inside plan.optimize (capture, rewrite, dispatch) less "
+     "the host.read_us accrued inside it: the planner's and "
+     "dispatcher's own time; timed only while counters are enabled"),
     ("plan.reads_trace", COUNTER, "traces",
      "referenced-column discovery traces actually run (eval_shape over "
      "one predicate/expression; cache-hit captures skip these)"),
@@ -353,21 +360,17 @@ METRICS: Dict[str, MetricSpec] = _specs(
     ("stats.fingerprints", GAUGE, "plans",
      "distinct plan fingerprints currently held by the run-stats store"),
     # compilation observability (observe/compile.py;
-    # docs/observability.md "compile tracking"): every jit build through
-    # an instrumented kernel factory is a measured event
+    # docs/observability.md "compile tracking"): every XLA compile is a
+    # measured event, counted by one jax.monitoring listener
     ("compile.builds", COUNTER, "builds",
-     "jit programs built: first concrete dispatch of a new shape "
-     "signature through an instrumented kernel factory (trace + XLA "
-     "compile paid here)"),
+     "XLA executables compiled or loaded from the persistent cache "
+     "(JAX's backend-compile event: every program, not only those of "
+     "the kernel factories)"),
     ("compile.build_us", COUNTER, "us",
-     "wall-clock of compile.builds events (async dispatch: trace + "
-     "lowering + compile + enqueue; device execution excluded) — "
+     "duration of the compile.builds events as JAX reports them "
+     "(XLA compile or cache load; tracing and lowering excluded) — "
      "report.totals['compile_ms'] and QueryHandle.compile_ms derive "
      "from the per-query attribution of the same events"),
-    ("compile.trace_us", COUNTER, "us",
-     "the pure tracing share of builds, measured via one eval_shape "
-     "pre-pass while counters are enabled (production dispatch skips "
-     "the pre-pass, so this is an observability-mode number)"),
     ("compile.cache_hits", COUNTER, "hits",
      "kernel-factory cache hits (the program already existed)"),
     ("compile.cache_misses", COUNTER, "misses",

@@ -153,15 +153,16 @@ def _read_counts(cnt_dev):
     import numpy as np
 
     from .. import faults, resilience, trace
-    trace.count("host.read")  # one blocking count read (sync-floor unit)
 
     def attempt():
         faults.check("compact.read_counts")
         return np.asarray(jax.device_get(cnt_dev))
 
-    # the read is side-effect-free, so a transient transfer failure
-    # (a transfer blip, injected chaos) is safely re-tried
-    return resilience.retry_call(attempt, point="compact.read_counts")
+    # one blocking count read (sync-floor unit).  The read is
+    # side-effect-free, so a transient transfer failure (a transfer
+    # blip, injected chaos) is safely re-tried
+    with trace.host_read():
+        return resilience.retry_call(attempt, point="compact.read_counts")
 
 
 class _DeferredState(threading.local):
@@ -270,14 +271,14 @@ def flush_pending_with(extra):
     if not batch and not extra:
         return _deferred.ok, []
     from .. import faults, resilience, trace
-    trace.count("host.read")  # ONE batched read for the whole flush
 
     def attempt():
         faults.check("compact.flush")
         return jax.device_get([cnt for _, _, _, cnt, _ in batch]
                               + list(extra))
 
-    values = resilience.retry_call(attempt, point="compact.flush")
+    with trace.host_read():  # ONE batched read for the whole flush
+        values = resilience.retry_call(attempt, point="compact.flush")
     # Entries queue in dispatch order, so everything after the first
     # undersized dispatch computed on truncated inputs — its counts are
     # poisoned (a zero-filled exchange can explode a downstream join

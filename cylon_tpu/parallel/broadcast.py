@@ -59,7 +59,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import trace
 from ..analysis import plan_check
-from ..observe.compile import kernel_factory
+from ..observe.compile import kernel_factory, named_jit
 from ..observe.locks import OrderedLock
 from ..analysis._abstract import is_abstract
 from ..config import broadcast_join_threshold
@@ -112,8 +112,8 @@ def _gather_fn(mesh, axis: str, cap: int, outcap: int, head_only: bool):
     spec = P(axis)
     # check_vma=False: the all_gathered intermediates are replicated,
     # which shard_map cannot statically infer (same note as shuffle.py)
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec, spec),
-                             out_specs=(spec, spec), check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=(spec, spec),
+                               out_specs=(spec, spec), check_vma=False))
 
 
 def rows_if_small(dt: DTable, threshold: Optional[int],

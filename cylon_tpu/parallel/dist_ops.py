@@ -21,6 +21,7 @@ splitters.
 """
 from __future__ import annotations
 
+import functools
 import threading
 from contextlib import contextmanager
 from typing import List, Sequence, Tuple, Union
@@ -35,7 +36,7 @@ from .. import trace
 from ..analysis import plan_check
 from ..config import JoinAlgorithm, JoinConfig
 from ..dtypes import DataType, is_dictionary_encoded
-from ..observe.compile import kernel_factory
+from ..observe.compile import kernel_factory, named_jit
 from ..ops import compact as ops_compact
 from ..ops import gather as ops_gather
 from ..ops import groupby as ops_groupby
@@ -63,8 +64,8 @@ def _mask_fn(mesh, axis: str, cap: int):
     def kernel(cnt_blk):
         return jnp.arange(cap) < cnt_blk[0]
 
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=P(axis), out_specs=P(axis)))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=P(axis), out_specs=P(axis)))
 
 
 def _row_mask(dt: DTable) -> jax.Array:
@@ -107,9 +108,9 @@ def _hash_pids_fn(mesh, axis: str, cap: int, nparts: int, use_pallas: bool):
 
     spec = P(axis)
     # check_vma=False: pallas_call can't declare varying-mesh-axes metadata
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec,) * 3, out_specs=spec,
-                             check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec,) * 3, out_specs=spec,
+                               check_vma=False))
 
 
 def hash_uses_pallas(mesh) -> bool:
@@ -305,10 +306,10 @@ def _join_phase1_fn(mesh, axis: str, how: str, alg: str, carried: bool):
     spec = P(axis)
     # check_vma=False: the all_gathered counts are replicated, which
     # shard_map cannot statically infer
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec,) * 8,
-                             out_specs=(spec, P()),
-                             check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec,) * 8,
+                               out_specs=(spec, P()),
+                               check_vma=False))
 
 
 @kernel_factory
@@ -336,8 +337,8 @@ def _join_phase2_fn(mesh, axis: str, how: str, alg: str, capacity: int,
         return louts, routs, cnt[None]
 
     spec = P(axis)
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec,) * 5, out_specs=(spec,) * 3))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec,) * 5, out_specs=(spec,) * 3))
 
 
 @plan_check.instrument
@@ -471,8 +472,8 @@ def _fk_probe_fn(mesh, axis: str, cap_l: int, cap_r: int, lo: int, hi: int,
     spec = P(axis)
     nargs = 6 + int(has_lmask)
     # check_vma=False: the all_gathered counts are replicated
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * nargs,
-                             out_specs=(spec, spec, P()), check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * nargs,
+                               out_specs=(spec, spec, P()), check_vma=False))
 
 
 @kernel_factory
@@ -484,8 +485,8 @@ def _fk_rgather_fn(mesh, axis: str, nleaves: int, fill: bool):
         return tuple(ops_gather.take_many(r_leaves, ri, fill_null=fill))
 
     spec = P(axis)
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec, spec),
-                             out_specs=spec))
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=(spec, spec),
+                               out_specs=spec))
 
 
 def _fk_violations(per_shard):
@@ -1019,8 +1020,8 @@ def _setop_fn(mesh, axis: str, op: str, cap_a: int, cap_b: int,
         return outs, count[None]
 
     spec = P(axis)
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec,) * 4, out_specs=(spec, spec)))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec,) * 4, out_specs=(spec, spec)))
 
 
 def _dist_set_op(a: DTable, b: DTable, op: str) -> DTable:
@@ -1100,10 +1101,10 @@ def _groupby_phase1_fn(mesh, axis: str, cap: int, has_where: bool):
     spec = P(axis)
     nargs = 4 if has_where else 3
     # check_vma=False: the all_gathered counts are replicated
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec,) * nargs,
-                             out_specs=(spec, spec, P()),
-                             check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec,) * nargs,
+                               out_specs=(spec, spec, P()),
+                               check_vma=False))
 
 
 @kernel_factory
@@ -1138,8 +1139,8 @@ def _groupby_phase2_fn(mesh, axis: str, aggs: Tuple[str, ...], out_cap: int,
         return keys_out, outs, out_valids, ngroups[None]
 
     spec = P(axis)
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec,) * 4, out_specs=(spec,) * 4))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec,) * 4, out_specs=(spec,) * 4))
 
 
 @kernel_factory
@@ -1163,8 +1164,8 @@ def _dense_phase1_fn(mesh, axis: str, cap: int, lo: int, hi: int,
     spec = P(axis)
     nargs = 3 if has_where else 2
     # check_vma=False: the all_gathered counts are replicated
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * nargs,
-                             out_specs=(spec, spec, P()), check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * nargs,
+                               out_specs=(spec, spec, P()), check_vma=False))
 
 
 @kernel_factory
@@ -1185,8 +1186,8 @@ def _dense_phase2_fn(mesh, axis: str, aggs: Tuple[str, ...], out_cap: int,
         return ((kd, kv), outs, ovals, ng[None])
 
     spec = P(axis)
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec,) * 3, out_specs=(spec,) * 4))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec,) * 3, out_specs=(spec,) * 4))
 
 
 # Last bucketed group-count capacity per groupby signature (the optimistic
@@ -1413,8 +1414,8 @@ def _mod_pids_fn(mesh, axis: str, cap: int, lo: int, nparts: int,
         return jnp.where(mask, pid, jnp.int32(nparts))
 
     spec = P(axis)
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 3,
-                             out_specs=spec))
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 3,
+                               out_specs=spec))
 
 
 def _dist_groupby_dense(dt: DTable, sh: DTable, kc: DColumn, key_id: int,
@@ -1890,9 +1891,9 @@ def _psum_combine_fn(mesh, axis: str, cap: int, domains: Tuple,
     # check_vma=False: the psum'd packs are replicated; every shard
     # emits the identical decoded block as its own P(axis) slice (the
     # replicate_table idiom)
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * nargs,
-                             out_specs=(spec, spec, spec),
-                             check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * nargs,
+                               out_specs=(spec, spec, spec),
+                               check_vma=False))
 
 
 def _fused_psum_groupby(dt: DTable, key_ids: List[int], aggregations,
@@ -2189,10 +2190,10 @@ def _sketch_partial_fn(mesh, axis: str, cap: int, total_cap: int,
         return tuple(keys_out), tuple(outs), ngroups[None]
 
     spec = P(axis)
-    return jax.jit(shard_map(
-        kernel, mesh=mesh,
-        in_specs=(spec,) * (5 if has_where else 4),
-        out_specs=(spec, spec, spec)))
+    return named_jit(shard_map(
+          kernel, mesh=mesh,
+          in_specs=(spec,) * (5 if has_where else 4),
+          out_specs=(spec, spec, spec)))
 
 
 @kernel_factory
@@ -2260,8 +2261,8 @@ def _sketch_combine_fn(mesh, axis: str, cap: int,
         return tuple(keys_out), tuple(outs), ngroups[None]
 
     spec = P(axis)
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 3,
-                             out_specs=(spec, spec, spec)))
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 3,
+                               out_specs=(spec, spec, spec)))
 
 
 def _sketch_state_table(ctx, key_meta_cols, keys_out, sk_outs, kinds,
@@ -2511,9 +2512,9 @@ def _scalar_agg_fn(mesh, axis: str, cap: int, aggs: Tuple[str, ...],
     spec = P(axis)
     nargs = 3 if has_where else 2
     # check_vma=False: psum outputs are replicated
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * nargs,
-                             out_specs=((P(),) * len(aggs),) * 2,
-                             check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * nargs,
+                               out_specs=((P(),) * len(aggs),) * 2,
+                               check_vma=False))
 
 
 @plan_check.instrument
@@ -2585,8 +2586,8 @@ def _sample_fn(mesh, axis: str, cap: int, nsamples: int, ascending: bool):
         return vals, ok
 
     spec = P(axis)
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec,) * 3, out_specs=(spec, spec)))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec,) * 3, out_specs=(spec, spec)))
 
 
 @kernel_factory
@@ -2615,9 +2616,9 @@ def _pool_splitters_fn(mesh, axis: str, nsides: int, nparts: int,
 
     spec = P(axis)
     # check_vma=False: the pooled splitters are replicated
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec,) * (2 * nsides), out_specs=P(),
-                             check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec,) * (2 * nsides), out_specs=P(),
+                               check_vma=False))
 
 
 def _sample_splitters(sides: Sequence[Tuple[DTable, int]], ascending: bool
@@ -2639,7 +2640,7 @@ def _sample_splitters(sides: Sequence[Tuple[DTable, int]], ascending: bool
                               ascending)(*vals, *oks)
 
 
-@jax.jit
+@functools.partial(named_jit, name="dist_ops._range_pids_kernel")
 def _range_pids_kernel(col, validity, mask, splitters, nparts_arr, last_arr):
     pid = jnp.searchsorted(splitters, col, side="right").astype(jnp.int32)
     if validity is not None:
@@ -2647,7 +2648,7 @@ def _range_pids_kernel(col, validity, mask, splitters, nparts_arr, last_arr):
     return jnp.where(mask, pid, nparts_arr)
 
 
-@jax.jit
+@functools.partial(named_jit, name="dist_ops._range_pids_desc_kernel")
 def _range_pids_desc_kernel(col, validity, mask, splitters, nparts_arr,
                             last_arr):
     # splitters descend; a row's partition is the count of splitters > value
@@ -2666,8 +2667,10 @@ def _range_pids(dt: DTable, key_i: int, splitters: jax.Array,
         return jnp.where(mask, jnp.int32(0), jnp.int32(nparts))
     sp = splitters.astype(c.data.dtype)
     fn = _range_pids_kernel if ascending else _range_pids_desc_kernel
-    return fn(c.data, c.validity, mask, sp, jnp.int32(nparts),
-              jnp.int32(nparts - 1))
+    # host scalars: a jnp.int32(...) here would be an eager device
+    # program of its own per call
+    return fn(c.data, c.validity, mask, sp, np.int32(nparts),
+              np.int32(nparts - 1))
 
 
 # ---------------------------------------------------------------------------
@@ -2774,7 +2777,8 @@ def _predicate_mask(dt: DTable, predicate) -> jax.Array:
         def kernel(base_mask, leaves):
             return _masked_predicate(names, predicate, base_mask, leaves)
 
-        fn = _cache_put(key, jax.jit(kernel))
+        fn = _cache_put(key, named_jit(kernel,
+                                       name="dist_ops._predicate_mask"))
     leaves = tuple((c.data, c.validity) for c in dt.columns)
     base = _row_mask(dt) if dt.pending_mask is None else dt.pending_mask
     return fn(base, leaves)
@@ -2822,9 +2826,9 @@ def _compact_survivors(dt: DTable, mask: jax.Array, cnts, hint_key,
                 return outs, count[None].astype(jnp.int32)
 
             spec = P(axis)
-            p2 = _cache_put(key2, jax.jit(shard_map(
+            p2 = _cache_put(key2, named_jit(shard_map(
                 gather_kernel, mesh=mesh, in_specs=(spec, spec),
-                out_specs=(spec, spec))))
+                out_specs=(spec, spec)), name="dist_ops._compact_survivors"))
         return p2(mask, leaves)
 
     if post is None:
@@ -2887,10 +2891,11 @@ def dist_select(dt: DTable, predicate, params=(), compact: bool = True
         spec = P(axis)
         # check_vma=False: the all_gathered counts are replicated (and so
         # are the params)
-        p1 = _cache_put(key1, jax.jit(shard_map(
+        p1 = _cache_put(key1, named_jit(shard_map(
             mask_kernel, mesh=mesh,
             in_specs=(spec, spec, P()) + ((spec,) if has_pm else ()),
-            out_specs=(spec, P()), check_vma=False)))
+            out_specs=(spec, P()), check_vma=False),
+            name="dist_ops.dist_select"))
     leaves = tuple((c.data, c.validity) for c in dt.columns)
     args = (dt.counts, leaves, tuple(params)) + (
         (dt.pending_mask,) if has_pm else ())
@@ -2959,8 +2964,8 @@ def _semi_mask_dense_fn(mesh, axis: str, cap_l: int, cap_r: int,
     spec = P(axis)
     nargs = 6 + int(has_lmask) + int(has_rmask)
     # check_vma=False: the all_gathered counts are replicated
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * nargs,
-                             out_specs=(spec, P()), check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * nargs,
+                               out_specs=(spec, P()), check_vma=False))
 
 
 @kernel_factory
@@ -2979,8 +2984,8 @@ def _semi_mask_fn(mesh, axis: str, cap_l: int, cap_r: int, anti: bool):
 
     spec = P(axis)
     # check_vma=False: the all_gathered counts are replicated
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 6,
-                             out_specs=(spec, P()), check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 6,
+                               out_specs=(spec, P()), check_vma=False))
 
 
 def _dist_semi_or_anti(left: DTable, right: DTable, left_on, right_on,
@@ -3175,11 +3180,17 @@ def dist_with_column(dt: DTable, name: str, fn, out_type,
         # declared type must match what the device actually stores
         out_type = {Type.INT64: Type.INT32, Type.UINT64: Type.UINT32,
                     Type.DOUBLE: Type.FLOAT}.get(out_type, out_type)
-    jfn = _select_cache.get(("withcol", fn))
+    dtype = device_dtype(out_type)
+    key = ("withcol", fn, dtype)
+    jfn = _select_cache.get(key)
     if jfn is None:
-        jfn = _cache_put(("withcol", fn), jax.jit(fn))
+        def with_column(env):
+            # the cast rides the program, not an eager device op of its own
+            return fn(env).astype(dtype)
+
+        jfn = _cache_put(key, named_jit(with_column,
+                                        name="dist_ops.dist_with_column"))
     out = jfn(_env(dt.columns))
-    out = out.astype(device_dtype(out_type))
     validity = None
     for n in validity_from:
         v = dt.column(n).validity
@@ -3211,8 +3222,8 @@ def _local_sort_multi_fn(mesh, axis: str, cap: int, nkeys: int,
         return tuple(ops_gather.take_many(leaves, order, fill_null=False))
 
     spec = P(axis)
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec,) * 3, out_specs=spec))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec,) * 3, out_specs=spec))
 
 
 @plan_check.instrument
@@ -3260,8 +3271,8 @@ def _local_sort_fn(mesh, axis: str, cap: int, ascending: bool):
         return tuple(ops_gather.take_many(leaves, order, fill_null=False))
 
     spec = P(axis)
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec,) * 3, out_specs=spec))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec,) * 3, out_specs=spec))
 
 
 @plan_check.instrument

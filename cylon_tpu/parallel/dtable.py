@@ -19,6 +19,7 @@ dictionaries onto a shared one at ingest.
 """
 from __future__ import annotations
 
+import functools
 import threading
 from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Sequence, Union
@@ -30,7 +31,7 @@ import numpy as np
 from .._jax_compat import trace_state_clean
 from ..analysis._abstract import is_abstract
 from ..context import CylonContext
-from ..observe.compile import kernel_factory
+from ..observe.compile import kernel_factory, named_jit
 from ..dtypes import DataType, is_dictionary_encoded
 from ..ops import compact as ops_compact
 from ..status import Code, CylonError, Status
@@ -458,8 +459,8 @@ class DTable:
                                      arrow_type=c.arrow_type))
             return Table(self.ctx, cols_a)
         from .. import trace
-        trace.count("host.read")  # one batched export transfer
-        hosts = jax.device_get(pulls)
+        with trace.host_read():  # one batched export transfer
+            hosts = jax.device_get(pulls)
         cols: List[Column] = []
         hi = 0
         for c in self.columns:
@@ -777,7 +778,7 @@ class DTable:
                 f"cap={self.cap}{spilled}]({cols})")
 
 
-@jax.jit
+@functools.partial(named_jit, name="dtable._export_take")
 def _export_take(a: jax.Array, idx: jax.Array) -> jax.Array:
     """Device-side row compaction for export (re-traced per shape bucket)."""
     return jnp.take(a, idx, axis=0)
@@ -794,8 +795,8 @@ def _replicate_counts_fn(mesh, axis: str):
 
     # check_vma=False: the all_gathered output is replicated, which
     # shard_map cannot statically infer
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=P(axis),
-                             out_specs=P(), check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=P(axis),
+                               out_specs=P(), check_vma=False))
 
 
 @kernel_factory
@@ -835,9 +836,9 @@ def _head_fn(mesh, axis: str, cap: int, n: int, has_v):
 
     spec = P(axis)
     # check_vma=False: psum outputs are replicated
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec, spec), out_specs=(P(), P()),
-                             check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec, spec), out_specs=(P(), P()),
+                               check_vma=False))
 
 
 _ARENA_CAP = 256 << 20

@@ -46,6 +46,7 @@ from .._jax_compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .. import trace
+from ..observe.compile import named_jit
 
 __all__ = ["MeshProfile", "mesh_fingerprint", "probe", "get_profile",
            "put_profile", "clear_profiles", "COLLECTIVES", "TRANSFERS"]
@@ -152,8 +153,9 @@ def _a2a_probe_fn(mesh, axis: str, nparts: int, m: int, spec_axes=None):
                                tiled=True)
         return jnp.sum(y).reshape(1)
 
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=spec, out_specs=spec))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=spec, out_specs=spec),
+                     name="meshprobe._a2a_probe_fn")
 
 
 @functools.lru_cache(maxsize=None)
@@ -165,8 +167,9 @@ def _ppermute_probe_fn(mesh, axis: str, nparts: int, spec_axes=None):
         y = jax.lax.ppermute(x_blk, axis, perm)
         return jnp.sum(y).reshape(1)
 
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=spec, out_specs=spec))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=spec, out_specs=spec),
+                     name="meshprobe._ppermute_probe_fn")
 
 
 @functools.lru_cache(maxsize=None)
@@ -179,9 +182,10 @@ def _allgather_probe_fn(mesh, axis: str, spec_axes=None):
 
     # check_vma=False: the gathered intermediate is replicated, which
     # shard_map cannot statically infer (same note as broadcast.py)
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=spec, out_specs=spec,
-                             check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=spec, out_specs=spec,
+                               check_vma=False),
+                     name="meshprobe._allgather_probe_fn")
 
 
 def _fit(points) -> Tuple[float, float]:

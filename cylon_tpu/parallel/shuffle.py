@@ -47,7 +47,7 @@ from .._jax_compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .. import trace
-from ..observe.compile import kernel_factory
+from ..observe.compile import kernel_factory, named_jit
 from ..observe.locks import OrderedLock
 from ..ops import compact as ops_compact
 from ..ops import gather as ops_gather
@@ -270,9 +270,9 @@ def _counts_fn(mesh, axis: str, nparts: int):
 
     # check_vma=False: the all_gather makes the output replicated, which
     # shard_map cannot statically infer
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=P(axis), out_specs=P(),
-                             check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=P(axis), out_specs=P(),
+                               check_vma=False))
 
 
 @kernel_factory
@@ -344,7 +344,7 @@ def _exchange_fn(mesh, axis: str, nparts: int, block: int, outcap: int,
     f = shard_map(kernel, mesh=mesh,
                   in_specs=(spec, spec),
                   out_specs=(spec, spec))
-    return jax.jit(f)
+    return named_jit(f)
 
 
 # ---------------------------------------------------------------------------
@@ -434,7 +434,7 @@ def _ring_exchange_fn(mesh, axis: str, nparts: int, block: int,
     f = shard_map(kernel, mesh=mesh,
                   in_specs=(spec, spec),
                   out_specs=(spec, spec))
-    return jax.jit(f)
+    return named_jit(f)
 
 
 @kernel_factory
@@ -480,10 +480,10 @@ def _allgather_exchange_fn(mesh, axis: str, nparts: int, outcap: int):
 
     # check_vma=False: the all_gathered intermediates are replicated,
     # which shard_map cannot statically infer (same note as broadcast.py)
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(P(axis), P(axis)),
-                             out_specs=(P(axis), P(axis)),
-                             check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(P(axis), P(axis)),
+                               out_specs=(P(axis), P(axis)),
+                               check_vma=False))
 
 
 def _staged_exchange(ctx, pid, leaves, choice, outcap_total: int):
@@ -644,8 +644,8 @@ def _rank_fn(mesh, axis: str, nparts: int):
                        - jnp.take(offs, sorted_pid).astype(jnp.int32))
         return jnp.zeros((cap,), jnp.int32).at[order].set(rank_sorted)
 
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=P(axis), out_specs=P(axis)))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=P(axis), out_specs=P(axis)))
 
 
 @kernel_factory
@@ -659,7 +659,7 @@ def _slice_pids_fn(nparts: int):
         keep = (rank >= lo) & (rank < hi) & (pid < nparts)
         return jnp.where(keep, pid, jnp.int32(nparts))
 
-    return jax.jit(f)
+    return named_jit(f)
 
 
 @kernel_factory
@@ -698,7 +698,7 @@ def _fold_fn(mesh, axis: str, incap: int, outcap: int, fresh: bool):
 
         f = shard_map(kernel, mesh=mesh,
                       in_specs=(P(axis),) * 4, out_specs=(P(axis), P(axis)))
-    return jax.jit(f)
+    return named_jit(f)
 
 
 @kernel_factory
@@ -768,7 +768,7 @@ def _fold_combine_fn(mesh, axis: str, spec, incap: int, acc_cap: int,
 
         f = shard_map(kernel, mesh=mesh,
                       in_specs=(P(axis),) * 4, out_specs=(P(axis), P(axis)))
-    return jax.jit(f)
+    return named_jit(f)
 
 
 # ---------------------------------------------------------------------------
@@ -795,7 +795,7 @@ def _fast_targets_fn(nparts: int, fast: int):
         return jnp.where(pid < nparts, pid % fast,
                          jnp.int32(fast)).astype(jnp.int32)
 
-    return jax.jit(kernel)
+    return named_jit(kernel)
 
 
 @kernel_factory
@@ -813,7 +813,7 @@ def _stage2_pids_fn(mesh, spec_axes, nparts: int, fast: int, nslow: int,
 
     f = shard_map(kernel, mesh=mesh, in_specs=(spec, spec),
                   out_specs=spec)
-    return jax.jit(f)
+    return named_jit(f)
 
 
 @kernel_factory
@@ -835,8 +835,8 @@ def _slow_counts_fn(mesh, spec_axes, slow_axis: str, fast_axis: str,
 
     # check_vma=False: the all_gather replicates the output, which
     # shard_map cannot statically infer (same note as _counts_fn)
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=(spec, spec),
-                             out_specs=P(), check_vma=False))
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=(spec, spec),
+                               out_specs=P(), check_vma=False))
 
 
 @kernel_factory
@@ -884,7 +884,7 @@ def _slow_cell_fn(mesh, spec_axes, slow_axis: str, nparts: int, fast: int,
 
     f = shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
                   out_specs=(spec, spec))
-    return jax.jit(f)
+    return named_jit(f)
 
 
 def _hierarchical_exchange(ctx, pid, leaves, counts: np.ndarray,

@@ -44,7 +44,7 @@ from jax.sharding import PartitionSpec as P
 from .. import trace
 from ..analysis import plan_check
 from ..config import JoinConfig
-from ..observe.compile import kernel_factory
+from ..observe.compile import kernel_factory, named_jit
 from ..observe.locks import OrderedLock
 from ..ops import compact as ops_compact
 from ..ops import gather as ops_gather
@@ -57,11 +57,10 @@ from .dtable import DColumn, DTable
 def _slice_fn(nparts: int, cap: int, lo: int, hi: int):
     w = hi - lo
 
-    @jax.jit
     def f(a):
         return a.reshape(nparts, cap)[:, lo:hi].reshape(nparts * w)
 
-    return f
+    return named_jit(f)
 
 
 def _slice_rows(dt: DTable, lo: int, hi: int) -> DTable:
@@ -100,8 +99,8 @@ def _repack_fn(mesh, axis: str, caps: Tuple[int, ...], outcap: int,
         return outs, total[None].astype(jnp.int32)  # outs: (d, v)
 
     spec = P(axis)
-    return jax.jit(shard_map(kernel, mesh=mesh,
-                             in_specs=(spec, spec), out_specs=(spec, spec)))
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec, spec), out_specs=(spec, spec)))
 
 
 def _concat_compact(parts: List[DTable]) -> DTable:

@@ -65,7 +65,9 @@ def optimize(ctx, op, tables=None):
     the compiled-plan cache (plan/executor.py).  With the optimizer
     disabled (``CYLON_OPTIMIZER=0`` / ``config.set_optimizer_enabled``)
     the plan runs eagerly instead — the A/B lever bench uses."""
+    from .. import trace
     from ..config import optimizer_enabled
-    if not optimizer_enabled():
-        return op(tables) if tables is not None else op()
-    return run(ctx, op, tables)
+    with trace.planner():
+        if not optimizer_enabled():
+            return op(tables) if tables is not None else op()
+        return run(ctx, op, tables)

@@ -602,7 +602,9 @@ class LogicalTable:
         return self.materialize().head(n)
 
     def to_pandas(self):
-        return self.to_table().to_pandas()
+        from .. import trace
+        with trace.annotate("export"):
+            return self.to_table().to_pandas()
 
     def __repr__(self) -> str:
         cols = ", ".join(f"{c.name}:{c.dtype.type.name}"

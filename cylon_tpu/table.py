@@ -330,7 +330,13 @@ class Table:
         All columns missing a host cache transfer in ONE batched
         ``device_get`` (per-column pulls would pay one device round trip
         each); columns exported from a DTable carry their host copies
-        already and transfer nothing."""
+        already and transfer nothing.  A ``cylon.export`` profiler
+        span."""
+        from . import trace
+        with trace.annotate("export"):
+            return self._to_arrow()
+
+    def _to_arrow(self):
         import pyarrow as pa
 
         from .analysis._abstract import PlanExportReached, is_abstract
@@ -428,7 +434,9 @@ class Table:
                     "Column.with_data"))
 
     def to_pandas(self):
-        return self.to_arrow().to_pandas()
+        from . import trace
+        with trace.annotate("export"):
+            return self._to_arrow().to_pandas()
 
     # -- schema --------------------------------------------------------------
 

@@ -27,17 +27,30 @@ This module is the structured equivalent:
     XLA-level trace from ``profile()`` (docs/observability.md).
   * ``profile(path)`` — wraps ``jax.profiler.trace`` for XLA-level traces
     viewable in TensorBoard/Perfetto.
+  * profiler spans — every ``span``/``span_sync`` is ALSO a
+    ``jax.profiler.TraceAnnotation`` named ``cylon.<name>``, always (no
+    sync, no ``CYLON_TRACE``): the framework's phases land in the
+    profiler's own trace on the device trace's clock.  ``hard_sync``'s
+    blocking read is ``cylon.sync``; ``host_read`` marks the engine's
+    blocking device→host reads (``cylon.host.read``) and ``planner``
+    the planner's extent (``cylon.plan.optimize``); ``annotate`` is the
+    bare span for host-side phases such as ``cylon.export``.  Off the
+    profiler an annotation costs about a microsecond.
 
-Enable with ``CYLON_TRACE=1`` in the environment or ``trace.enable()``.
+Enable span recording with ``CYLON_TRACE=1`` in the environment or
+``trace.enable()``.
 """
 from __future__ import annotations
 
 import contextlib
+import math
 import os
 import threading
 import time
 from collections import OrderedDict
 from typing import Dict, Iterator, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from . import observe
 
@@ -48,8 +61,16 @@ __all__ = [
     "counters", "snapshot", "report", "bench_line", "export_chrome_trace",
     "profile", "hard_sync", "trace_context", "current_trace_id",
     "record_span", "finish_trace", "set_tail_budget", "tail_budget",
-    "tail_stats",
+    "tail_stats", "annotate", "host_read", "planner",
 ]
+
+# the profiler-span namespace of the engine's own phases
+PREFIX = "cylon."
+
+
+def annotate(name: str) -> TraceAnnotation:
+    """A profiler host span ``cylon.<name>`` (enter it with ``with``)."""
+    return TraceAnnotation(PREFIX + name)
 
 
 def hard_sync(tree) -> None:
@@ -63,8 +84,9 @@ def hard_sync(tree) -> None:
 
     Each barrier is itself observable: it bumps the ``trace.sync``
     counter (the per-query sync floor becomes a measured number instead
-    of an inference from docs/tpu_perf_notes.md) and, while tracing is
-    on, charges a nested ``sync`` span for the blocking read.
+    of an inference from docs/tpu_perf_notes.md), its blocking read is
+    the profiler span ``cylon.sync`` and, while tracing is on, it
+    charges a nested ``sync`` span for the read.
     """
     import jax
 
@@ -80,7 +102,8 @@ def hard_sync(tree) -> None:
     if not leaves:
         if not has_abstract:
             count("trace.sync")
-            jax.block_until_ready(tree)
+            with annotate("sync"):
+                jax.block_until_ready(tree)
         return
     reads = []
     for x in leaves:
@@ -93,13 +116,15 @@ def hard_sync(tree) -> None:
                 reads.append(shards[0].data.ravel()[:1])
     count("trace.sync")
     if not _enabled:
-        jax.device_get(reads)
+        with annotate("sync"):
+            jax.device_get(reads)
         return
     # charge the blocking read as a nested span, appended directly (the
     # span_sync machinery would call hard_sync again — recursion)
     st = _span_state()
     t0 = time.perf_counter()
-    jax.device_get(reads)
+    with annotate("sync"):
+        jax.device_get(reads)
     st.spans.append(("sync", st.depth, (time.perf_counter() - t0) * 1e3,
                      t0, threading.get_ident(), current_trace_id(), None))
 
@@ -238,10 +263,11 @@ def counters_enabled() -> bool:
 def span(name: str, sync=None) -> Iterator[None]:
     """Record wall-clock of the enclosed block under ``name``.
 
-    ``sync`` is an optional pytree of arrays the block produced; when
-    tracing is enabled the span blocks until they are ready so the time
-    charged to the phase includes the device work it dispatched.  Nested
-    spans record their depth for indented reports.
+    Always a profiler span ``cylon.<name>``.  ``sync`` is an optional
+    pytree of arrays the block produced; when tracing is enabled the
+    span blocks until they are ready so the time charged to the phase
+    includes the device work it dispatched.  Nested spans record their
+    depth for indented reports.
     """
     with span_sync(name) as sp:
         if sync is not None:
@@ -276,24 +302,68 @@ def span_sync(name: str) -> Iterator[_SyncSpan]:
     # only the body: the sync at span exit runs outside it.
     from .config import sanitize_guard
     guard = sanitize_guard() or contextlib.nullcontext()
-    if not _enabled:
-        with guard:
-            yield sp
-        return
-    st = _span_state()
-    depth = st.depth
-    st.depth = depth + 1
-    t0 = time.perf_counter()
-    try:
-        with guard:
-            yield sp
-    finally:
-        if sp._target is not None:
-            hard_sync(sp._target)
-        st.spans.append((name, depth, (time.perf_counter() - t0) * 1e3,
-                         t0, threading.get_ident(), current_trace_id(),
-                         None))
-        st.depth = depth
+    with annotate(name):
+        if not _enabled:
+            with guard:
+                yield sp
+            return
+        st = _span_state()
+        depth = st.depth
+        st.depth = depth + 1
+        t0 = time.perf_counter()
+        try:
+            with guard:
+                yield sp
+        finally:
+            if sp._target is not None:
+                hard_sync(sp._target)
+            st.spans.append((name, depth, (time.perf_counter() - t0) * 1e3,
+                             t0, threading.get_ident(), current_trace_id(),
+                             None))
+            st.depth = depth
+
+
+@contextlib.contextmanager
+def host_read() -> Iterator[None]:
+    """One blocking device→host read: bumps ``host.read``, runs inside
+    the profiler span ``cylon.host.read`` and, while counters are
+    enabled (and only then, so production reads make no timing call),
+    charges its host µs to ``host.read_us``."""
+    count("host.read")
+    with annotate("host.read"):
+        if not counters_enabled():
+            yield
+            return
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            us = math.ceil((time.perf_counter() - t0) * 1e6)
+            _tls.read_us = getattr(_tls, "read_us", 0) + us
+            count("host.read_us", us)
+
+
+@contextlib.contextmanager
+def planner() -> Iterator[None]:
+    """The planner's extent (``plan.optimize``: capture, rewrite,
+    dispatch): the profiler span ``cylon.plan.optimize`` and, while
+    counters are enabled, ``plan.host_us`` — its host µs less the
+    ``host.read_us`` accrued inside it on this thread.  A nested
+    planner is charged to the outermost."""
+    with annotate("plan.optimize"):
+        if not counters_enabled() or getattr(_tls, "planning", False):
+            yield
+            return
+        _tls.planning = True
+        r0 = getattr(_tls, "read_us", 0)
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            _tls.planning = False
+            us = (time.perf_counter() - t0) * 1e6 \
+                - (getattr(_tls, "read_us", 0) - r0)
+            count("plan.host_us", max(0, round(us)))
 
 
 def count(name: str, n: int = 1) -> None:

@@ -2,9 +2,11 @@
 tracking, measured peak memory, cost-model calibration, SLO alerting.
 
 Coverage contract:
-  * ``kernel_factory`` counts builds/hits/misses, times builds, skips
-    abstract plan runs, attributes per-query compile_ms, and detects
-    recompile storms naming the thrashing key component;
+  * every XLA compile is counted (the compile listener), hand-rolled
+    program caches included; ``kernel_factory`` counts hits/misses,
+    abstract plan runs count nothing, per-query compile_ms is
+    attributed, and recompile storms are detected naming the
+    thrashing key component;
   * ``devmem`` reads allocator truth where available and degrades to
     live-buffer accounting on CPU; EXPLAIN ANALYZE annotates every
     exchange with ``peak=predicted/observed bytes`` AND (with a probed
@@ -36,8 +38,8 @@ from cylon_tpu import Table, config, faults, observe, trace
 from cylon_tpu import logging as glog
 from cylon_tpu.observe import compile as obcompile
 from cylon_tpu.observe import devmem, doctor, flightrec
-from cylon_tpu.parallel import (DTable, dist_groupby, meshprobe,
-                                shuffle_table)
+from cylon_tpu.parallel import (DTable, dist_groupby, dist_select,
+                                meshprobe, shuffle_table)
 from cylon_tpu.serve import ServeSession
 
 
@@ -77,11 +79,12 @@ def test_kernel_factory_counts_builds_hits_and_signatures():
     @obcompile.kernel_factory
     def _diag_toy_fn(n: int):
         built.append(n)
-        return jax.jit(lambda x: x + n)
+        return obcompile.named_jit(lambda x: x + n)
 
+    # host inputs: no device op of the test's own compiles in between
+    x4, x8 = np.arange(4), np.arange(8)
     trace.enable_counters()
     trace.reset()
-    x4 = jnp.arange(4)
     _diag_toy_fn(1)(x4)
     c = trace.counters()
     assert c.get("compile.cache_misses", 0) == 1
@@ -94,7 +97,7 @@ def test_kernel_factory_counts_builds_hits_and_signatures():
     assert c.get("compile.builds", 0) == 1
     assert built == [1]
     # same key, NEW shape: jit re-traces — a second build, no miss
-    _diag_toy_fn(1)(jnp.arange(8))
+    _diag_toy_fn(1)(x8)
     c = trace.counters()
     assert c.get("compile.builds", 0) == 2
     assert c.get("compile.cache_misses", 0) == 1
@@ -104,39 +107,66 @@ def test_kernel_factory_counts_builds_hits_and_signatures():
     assert c.get("compile.cache_misses", 0) == 2
     assert c.get("compile.builds", 0) == 3
     assert built == [1, 2]
+    # counters off: the listener counts nothing
+    trace.disable_counters()
+    _diag_toy_fn(3)(x4)
+    assert trace.counters().get("compile.builds", 0) == 3
 
 
 def test_kernel_factory_passes_abstract_runs_through():
     @obcompile.kernel_factory
     def _diag_abs_fn(n: int):
-        return jax.jit(lambda x: x * n)
+        return obcompile.named_jit(lambda x: x * n)
 
+    x5 = np.arange(5, dtype=np.int32)
     trace.enable_counters()
     trace.reset()
     out = jax.eval_shape(lambda x: _diag_abs_fn(3)(x),
                          jax.ShapeDtypeStruct((5,), jnp.int32))
     assert out.shape == (5,)
-    # the abstract call built nothing and recorded nothing
+    # the abstract call compiled nothing and recorded nothing
     assert trace.counters().get("compile.builds", 0) == 0
-    # the first CONCRETE call still measures normally
-    _diag_abs_fn(3)(jnp.arange(5, dtype=jnp.int32))
+    # the first CONCRETE call still counts its compile
+    _diag_abs_fn(3)(x5)
     assert trace.counters().get("compile.builds", 0) == 1
 
 
 def test_attribute_compiles_collects_per_scope():
     @obcompile.kernel_factory
     def _diag_attr_fn(n: int):
-        return jax.jit(lambda x: x - n)
+        return obcompile.named_jit(lambda x: x - n)
 
+    x3 = np.arange(3)
     with obcompile.attribute_compiles() as events:
-        _diag_attr_fn(7)(jnp.arange(3))
+        _diag_attr_fn(7)(x3)
     assert len(events) == 1
-    assert events[0]["factory"].endswith("_diag_attr_fn")
+    # the program carries its factory's stable name
+    assert events[0]["program"].endswith("_diag_attr_fn)")
     assert events[0]["compile_ms"] > 0
     # outside the scope nothing is attributed
     with obcompile.attribute_compiles() as events2:
-        _diag_attr_fn(7)(jnp.arange(3))   # seen signature — no build
+        _diag_attr_fn(7)(x3)   # seen signature — no compile
     assert events2 == []
+
+
+def test_compile_outside_any_kernel_factory_counts(dctx, rng):
+    """A program of the hand-rolled select cache (``dist_ops.
+    _select_cache``), which no kernel factory builds: the old
+    first-call timer never saw it; the compile listener does."""
+    lt, _ = _tables(dctx, rng)
+
+    def _diag_pred(env):
+        return env["a"] > 0.25
+
+    trace.enable_counters()
+    trace.reset()
+    with obcompile.attribute_compiles() as events:
+        dist_select(lt, _diag_pred)
+    programs = {e["program"] for e in events}
+    assert "jit(dist_ops_dist_select)" in programs, programs
+    assert trace.counters().get("compile.builds", 0) >= len(events) >= 1
+    assert obcompile.program_names()["dist_ops_dist_select"] \
+        == "dist_ops.dist_select"
 
 
 def test_recompile_storm_warns_once_naming_the_component(monkeypatch):

@@ -72,6 +72,52 @@ def test_kernel_factory_unkeyed():
     assert _rules(sup) == []
 
 
+@pytest.mark.parametrize("body", [
+    "    def kernel(x):\n        return x + n\n    return jax.jit(kernel)\n",
+    "    @jax.jit\n    def f(x):\n        return x + n\n    return f\n",
+    "    return functools.partial(jax.jit, static_argnums=0)(\n"
+    "        lambda k, x: x + n)\n",
+])
+def test_program_unnamed_in_kernel_factory(body):
+    head = ("import functools, jax\n"
+            "from cylon_tpu.observe.compile import kernel_factory\n"
+            "@kernel_factory\n"
+            "def _probe_fn(n):\n")
+    assert _rules(head + body) == ["program-unnamed"]
+    lines = (head + body).splitlines()
+    flagged = graftlint.lint_source(head + body, "fixture.py")[0].line
+    lines[flagged - 1] += "  # graftlint: ok[program-unnamed]"
+    assert _rules("\n".join(lines) + "\n") == []
+    clean = ("from cylon_tpu.observe.compile import kernel_factory, "
+             "named_jit\n"
+             "@kernel_factory\n"
+             "def _probe_fn(n):\n"
+             "    def kernel(x):\n"
+             "        return x + n\n"
+             "    return named_jit(kernel)\n")
+    assert _rules(clean) == []
+
+
+def test_program_unnamed_in_program_cache():
+    pos = ("import jax\n"
+           "def select(key, kernel):\n"
+           "    return _cache_put(key, jax.jit(kernel))\n")
+    assert _rules(pos) == ["program-unnamed"]
+    clean = ("from cylon_tpu.observe.compile import named_jit\n"
+             "def select(key, kernel):\n"
+             "    return _cache_put(key, named_jit(kernel, "
+             "name='m.select'))\n")
+    assert _rules(clean) == []
+    # a plain lru_cache factory and a bare jit elsewhere are not this
+    # rule's business
+    other = ("import functools, jax\n"
+             "@functools.lru_cache(maxsize=None)\n"
+             "def _probe_fn(n):\n"
+             "    return jax.jit(lambda x: x + n)\n"
+             "f = jax.jit(lambda x: x)\n")
+    assert _rules(other) == []
+
+
 def test_jit_in_loop():
     pos = ("import jax\n"
            "for i in range(3):\n"
