@@ -10,9 +10,11 @@ ICI network does the rest (SURVEY.md §2.4).
 
 Variable-length sends meet XLA's static shapes with the two-phase plan:
 
-  phase 1 (counts)    per-shard ``bincount`` of target ids → ``[P, P]``
-                      matrix on host (a tiny transfer — the analogue of the
-                      reference's 8-int header messages).
+  phase 1 (counts)    per-shard count of target ids, one fused
+                      compare-and-sum per target (``_count_targets``, no
+                      scatter) → ``[P, P]`` matrix on host (a tiny transfer
+                      — the analogue of the reference's 8-int header
+                      messages).
   phase 2 (exchange)  rows grouped by target via one argsort, padded to a
                       size-class block ``M = bucket(max count)``, one
                       ``lax.all_to_all`` per column leaf, then receiver-side
@@ -56,6 +58,21 @@ from . import cost
 
 def _bcast(mask: jax.Array, like: jax.Array) -> jax.Array:
     return mask.reshape(mask.shape + (1,) * (like.ndim - mask.ndim))
+
+
+def _count_targets(ids: jax.Array, length: int) -> jax.Array:
+    """``jnp.bincount(ids, length=length)`` as int32, without a scatter.
+
+    ``bincount`` lowers to a combining scatter-add: at 4M ids into a
+    handful of bins every update collides in the same few slots and the
+    chip serializes them (≈ 37 ms, docs/tpu_perf_notes.md).  The
+    exchange only counts over mesh extents (``length`` ≤ P + 1), so one
+    compare-and-sum per bin costs ``cap × length`` compares, and XLA
+    fuses the sibling sums into one pass over the ids.  Same answer for
+    every input: negative ids count in bin 0 (bincount clips them), ids
+    ≥ ``length`` count nowhere."""
+    return jnp.stack([jnp.sum(ids <= 0 if t == 0 else ids == t,
+                              dtype=jnp.int32) for t in range(length)])
 
 
 # Last (send block, receive capacity) per shuffle signature — lets the next
@@ -265,8 +282,8 @@ def _counts_fn(mesh, axis: str, nparts: int):
     output would span non-addressable devices under multi-host."""
 
     def kernel(pid_blk):
-        cnt = jnp.bincount(pid_blk, length=nparts + 1)[:nparts]
-        return jax.lax.all_gather(cnt.astype(jnp.int32), axis)
+        cnt = _count_targets(pid_blk, nparts + 1)[:nparts]
+        return jax.lax.all_gather(cnt, axis)
 
     # check_vma=False: the all_gather makes the output replicated, which
     # shard_map cannot statically infer
@@ -296,7 +313,7 @@ def _exchange_fn(mesh, axis: str, nparts: int, block: int, outcap: int,
     def kernel(pid_blk, leaves):
         cap = pid_blk.shape[0]
         order = jnp.argsort(pid_blk, stable=True)     # rows grouped by target
-        cnt = jnp.bincount(pid_blk, length=nparts + 1)[:nparts].astype(jnp.int32)
+        cnt = _count_targets(pid_blk, nparts + 1)[:nparts]
         offs = jnp.concatenate([jnp.zeros((1,), cnt.dtype),
                                 jnp.cumsum(cnt)])[:-1]
         jj = jnp.arange(block, dtype=jnp.int32)[None, :]
@@ -636,7 +653,7 @@ def _rank_fn(mesh, axis: str, nparts: int):
     def kernel(pid_blk):
         cap = pid_blk.shape[0]
         order = jnp.argsort(pid_blk, stable=True)
-        cnt = jnp.bincount(pid_blk, length=nparts + 1)
+        cnt = _count_targets(pid_blk, nparts + 1)
         offs = jnp.concatenate([jnp.zeros((1,), cnt.dtype),
                                 jnp.cumsum(cnt)])[:-1]      # [nparts+1]
         sorted_pid = jnp.take(pid_blk, order)
@@ -830,7 +847,7 @@ def _slow_counts_fn(mesh, spec_axes, slow_axis: str, fast_axis: str,
         valid = jnp.arange(incap, dtype=jnp.int32) < cnt_blk[0]
         ts = jnp.where(valid & (pid_blk < nparts), pid_blk // fast,
                        jnp.int32(nslow))
-        c = jnp.bincount(ts, length=nslow + 1)[:nslow].astype(jnp.int32)
+        c = _count_targets(ts, nslow + 1)[:nslow]
         return jax.lax.all_gather(c, (slow_axis, fast_axis))
 
     # check_vma=False: the all_gather replicates the output, which

@@ -13,6 +13,8 @@ worker given this file should.  The persistent compilation cache is off
 around these compiles (an entry written without a chip cannot be read
 back).  x64 is off, as on the chip.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -82,6 +84,16 @@ def test_hash_partition_program_on_four_chips(mesh4):
     c = fn.lower(_sds((4,), jnp.int32, mesh4),
                  (_sds((4 * CAP,), jnp.int32, mesh4),), (None,)).compile()
     assert "tpu_custom_call" in c.as_text()
+
+
+def test_partition_counts_compile_without_a_scatter(mesh4):
+    # phase 1 of the exchange at bench size: the per-target counts are a
+    # compare-and-reduce, not bincount's 35-40 ms scatter-add
+    fn = shuffle._counts_fn.__wrapped__(mesh4, MESH_AXIS, 4)
+    hlo = fn.lower(_sds((4 * CAP,), jnp.int32, mesh4)).compile().as_text()
+    # op names only: the metadata quotes Python frames, this test's too
+    assert not re.search(r"\bscatter\(%", hlo)
+    assert re.search(r"\breduce\(%", hlo)
 
 
 def test_all_to_all_exchange_on_four_chips(mesh4):
