@@ -248,6 +248,17 @@ METRICS: Dict[str, MetricSpec] = _specs(
      "host time inside plan.optimize (capture, rewrite, dispatch) less "
      "the host.read_us accrued inside it: the planner's and "
      "dispatcher's own time; timed only while counters are enabled"),
+    ("ingest.rows_in", COUNTER, "rows",
+     "rows written into resident tables by DTable.append"),
+    ("ingest.rows_deleted", COUNTER, "rows",
+     "rows removed from resident tables by DTable.delete"),
+    ("ingest.host_bytes", COUNTER, "bytes",
+     "bytes that crossed the host-device boundary, either way, inside "
+     "the write spans (cylon.ingest.append / cylon.ingest.delete): the "
+     "staged delta, the delete's keys and its count reads"),
+    ("ingest.host_us", COUNTER, "us",
+     "host time inside the write spans less the host.read_us accrued "
+     "inside them; timed only while counters are enabled"),
     ("plan.reads_trace", COUNTER, "traces",
      "referenced-column discovery traces actually run (eval_shape over "
      "one predicate/expression; cache-hit captures skip these)"),

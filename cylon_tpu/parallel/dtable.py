@@ -116,14 +116,21 @@ class DTable:
         self.pending_mask = pending_mask   # [P*cap] bool or None
         self.pending_cnts = pending_cnts   # replicated [P] survivor counts
         self._counts_host: Optional[np.ndarray] = None
+        # host counts that bound ``counts`` from above while a delete
+        # that shrank them is unread (``delete`` leaves its new counts
+        # on the device; ``counts_host`` resolves them)
+        self._counts_bound: Optional[np.ndarray] = None
+        # the last delete's table shape and its candidate counts on the
+        # device, which size the next delete (``_note_delete_total``)
+        self._delete_seen = None
         # content-signature epoch (docs/serving.md "Materialized
         # subplans"): bumped on every logical-content change made
-        # through the ingest path (append).  Materialized views record
-        # the epoch of every base handle at capture time; a mismatch at
-        # probe time invalidates.  _deltas holds the last few appended
-        # batches keyed by the epoch they created, so a view whose tail
-        # is a mergeable aggregation can fold forward in O(delta)
-        # instead of recomputing.
+        # through the write path (append, delete).  Materialized views
+        # record the epoch of every base handle at capture time; a
+        # mismatch at probe time invalidates.  _deltas holds the last
+        # few appended batches keyed by the epoch they created, so a
+        # view whose tail is a mergeable aggregation can fold forward in
+        # O(delta) instead of recomputing.
         self._epoch: int = 0
         self._deltas: Dict[int, "DTable"] = {}
 
@@ -240,8 +247,18 @@ class DTable:
             ok, vals = ops_compact.flush_pending_with((c,))
             if not ok:
                 ops_compact._abort_if_poisoned()
-            self._counts_host = np.asarray(vals[0])
+            self._learn_counts(vals[0])
         return self._counts_host
+
+    def _learn_counts(self, counts) -> None:
+        """Cache the row counts just read; where an unread delete left
+        only a bound, the rows it deleted are now known."""
+        self._counts_host = np.asarray(counts)
+        if self._counts_bound is not None:
+            from .. import trace
+            trace.count("ingest.rows_deleted", int(
+                self._counts_bound.sum() - self._counts_host.sum()))
+            self._counts_bound = None
 
     @property
     def num_rows(self) -> int:
@@ -524,7 +541,7 @@ class DTable:
                 ops_compact._abort_if_poisoned()
             take = int(np.asarray(vals[0]))
             cnts = np.asarray(vals[1])
-            self._counts_host = cnts  # paid for either way — cache it
+            self._learn_counts(cnts)  # paid for either way — cache it
             if take >= int(cnts.sum()):  # the probe holds the whole table
                 return Table(self.ctx,
                              self._columns_from_host(vals, 2, take))
@@ -633,7 +650,7 @@ class DTable:
     def content_epoch(self) -> int:
         """Monotone logical-content version of this handle.  Layout
         changes (compaction, spill round trips) do NOT bump it; only
-        the ingest path (:meth:`append`) does."""
+        the write path (:meth:`append`, :meth:`delete`) does."""
         return self._epoch
 
     def delta_for(self, epoch: int) -> Optional["DTable"]:
@@ -647,50 +664,216 @@ class DTable:
     # a table ingesting forever does not retain its whole history.
     _DELTA_KEEP = 8
 
-    def append(self, other: "DTable") -> "DTable":
+    def append(self, other) -> "DTable":
         """UNION ALL ``other``'s rows into THIS handle, in place.
 
         This is the serving ingest path: identity-preserving — every
         holder of this handle (session tables, plans captured by
         value) observes the grown table — so it composes with the
-        serving tier's id()-keyed runtime signatures.  The merge
-        round-trips through Arrow (decode → concat → re-distribute),
-        which re-buckets capacity and rebuilds dictionary columns as
-        the sorted-unique superset; O(n+delta) host work, same as
-        ingest.  The *view* maintenance this enables is O(delta): the
-        appended batch is registered under the new content epoch and
+        serving tier's id()-keyed runtime signatures.  ``other`` is a
+        ``DTable`` of the same schema, an Arrow table, or a dict
+        ``{column: numpy array}`` (string columns as codes in this
+        table's dictionaries): how a refresh file arrives.
+
+        Only the delta crosses the host boundary: its rows go
+        host→device once, laid out per shard, and one program
+        (``_insert_fn``) writes them into the table's spare capacity
+        (copying each column: no buffer donation, as in ``delete``).
+        The shards with the most slack take the rows; a delta that
+        overflows the capacity re-buckets it on the device
+        (``ops.compact.next_bucket``).  After a ``delete`` the delta is
+        laid out and sent while the delete may still run, from the
+        counts before it (an upper bound); the host waits for the exact
+        counts only where they decide something: the layout across
+        several shards, or a capacity the bound would overflow.  Else
+        the new counts stay on the device, bounded by the old bound
+        plus the delta.  A delta whose
+        dictionary holds values this table's lacks merges into the
+        sorted superset, re-coding the base on the device.  The batch
+        is registered under the new content epoch, so
         :class:`~cylon_tpu.serve.matview.ViewStore` folds it through
-        the mergeable combine kernels instead of recomputing.
+        the mergeable combine kernels in O(delta) instead of
+        recomputing.
 
         Returns ``self`` (for chaining).
         """
-        import pyarrow as pa
+        from .. import trace
+        with trace.ingest("append"):
+            self._collapse_pending()
+            host = _host_delta(self, other)
+            m = len(host[0][0]) if host else 0
+            if m == 0:
+                return self
+            ctx = self.ctx
+            bound = self._counts_host if self._counts_host is not None \
+                else self._counts_bound
+            moved = 0
+            if bound is None:
+                bound = self.counts_host()
+                moved += bound.nbytes
+            unread = self._counts_host is None
+            base_leaves, rows, dicts = [], [], []
+            for c, (data, valid, dictionary) in zip(self.columns, host):
+                bdata, bvalid = c.data, c.validity
+                if dictionary is not None:
+                    dictionary, bmap, data = _merge_dictionary(
+                        c.dictionary, dictionary, data)
+                    if bmap is not None:
+                        bdata = _recode(jnp.asarray(bmap), bdata)
+                        trace.count("ingest.host_bytes", bmap.nbytes)
+                if valid is not None and bvalid is None:
+                    bvalid = _insert_validity(ctx.mesh, ctx.axis, self.cap)(
+                        self.counts)
+                elif valid is None and bvalid is not None:
+                    valid = np.ones(m, bool)
+                base_leaves.append((bdata, bvalid))
+                rows += [data.astype(bdata.dtype),
+                         None if valid is None else valid.astype(bool)]
+                dicts.append(dictionary)
+            take = _water_fill(bound, m)
+            dcap, blocks, staged = _stage(ctx, rows, take)
+            moved += sum(x.nbytes for x in blocks if x is not None)
+            if unread and (self.nparts > 1
+                           or int((bound + take).max()) > self.cap):
+                # the exact counts decide the layout across shards or a
+                # capacity the bound would overflow: wait for the delete
+                bound = self.counts_host()
+                moved += bound.nbytes
+                unread = False
+                if not np.array_equal(_water_fill(bound, m), take):
+                    # the delete left other shards emptiest: lay out again
+                    take = _water_fill(bound, m)
+                    dcap, blocks, staged = _stage(ctx, rows, take)
+                    moved += sum(x.nbytes for x in blocks if x is not None)
+            trace.count("ingest.host_bytes", moved)
+            *staged, take_dev = staged
+            grown = (bound.astype(np.int64) + take).astype(np.int32)
+            cap = self.cap if grown.max() <= self.cap else \
+                ops_compact.next_bucket(int(grown.max()), minimum=8)
+            delta_leaves = tuple(zip(staged[::2], staged[1::2]))
+            leaves, new_counts = _insert_fn(
+                ctx.mesh, ctx.axis, self.cap, cap, dcap)(
+                self.counts, take_dev, tuple(base_leaves), delta_leaves)
+            delta_cols = [DColumn(c.name, c.dtype, d, v, dictionary,
+                                  c.arrow_type)
+                          for c, (d, v), dictionary in zip(
+                              self.columns, delta_leaves, dicts)]
+            trace.count("ingest.rows_in", m)
+            delta = DTable(ctx, delta_cols, dcap, take_dev)
+            delta._counts_host = take.astype(np.int32)
+            self._replace_contents(
+                [replace(dc, data=d, validity=v)
+                 for dc, (d, v) in zip(delta_cols, leaves)],
+                cap, new_counts, None if unread else grown)
+            if unread:  # an unread delete's counts, grown by the delta
+                self._counts_bound = grown
+            self._deltas[self._epoch] = delta
+            for e in sorted(self._deltas):
+                if len(self._deltas) <= self._DELTA_KEEP:
+                    break
+                del self._deltas[e]
+            return self
 
-        self.verify_same_schema(other)
-        merged = pa.concat_tables(
-            [self.to_table().to_arrow(), other.to_table().to_arrow()]
-        ).combine_chunks()
-        grown = DTable.from_arrow(self.ctx, merged)
+    def delete(self, key_column: Union[int, str], keys) -> "DTable":
+        """Remove, in place, every row whose ``key_column`` value is in
+        ``keys`` (any integer array: sorted or not, contiguous or not;
+        a key that is not present is a no-op).
+
+        Works shard by shard on the device: the rows whose key lies in
+        ``[min(keys), max(keys)]`` are the candidates.  ``_delete_fn``
+        finds them by a running count, tests them against the sorted
+        keys and fills each hole below the new end with a surviving row
+        from above it: O(candidates) gathers and scatters past a pass
+        over the key column, and a copy of each column (the write
+        programs donate no buffers: derived tables may share them).  It
+        runs on the size class of the last delete of this table shape,
+        and finishes on the device whatever the count: a pass more for
+        each size class of candidates past it.  So the host reads
+        nothing to delete: the candidate counts size the next delete
+        once the device has them (``_note_delete_total``).  Only the
+        first delete of a shape counts its candidates first
+        (``_delete_scan_fn``) and reads the count; when there is none,
+        nothing changes.  Row order within a shard is not kept (the
+        DTable contract leaves it undefined).  The new counts stay on
+        the device until something reads them (``counts_host``), so the
+        host does not wait for the delete to finish.  The content epoch
+        bumps with no foldable delta, so a view over this table
+        recomputes.
+
+        Returns ``self`` (for chaining).
+        """
+        from .. import trace
+        with trace.ingest("delete"):
+            self._collapse_pending()
+            ki = self.column_index(key_column)
+            kc = self.columns[ki]
+            if is_dictionary_encoded(kc.dtype.type) or not jnp.issubdtype(
+                    kc.data.dtype, jnp.integer):
+                raise CylonError(Status(Code.TypeError,
+                    f"delete: key column {kc.name!r} is not an integer "
+                    f"column ({kc.dtype.type.name})"))
+            k = _delete_keys(keys, np.dtype(kc.data.dtype))
+            if not len(k):
+                return self
+            ctx = self.ctx
+            kcap = ops_compact.next_bucket(len(k), minimum=8)
+            kdev = jax.device_put(
+                np.concatenate([k, np.full(kcap - len(k), k[-1], k.dtype)]),
+                ctx.replicated())
+            moved = kdev.nbytes * self.nparts  # one copy per chip
+            counts, cap = self.counts, self.cap
+            shape = (ctx.mesh, cap, ki)
+            seen = _note_delete_total(self)
+            if seen is not None:
+                moved += seen.nbytes
+            sizes = ops_compact.hint_value(_delete_cap_hints, shape)
+            if sizes is None:  # the first delete of this shape
+                per_shard = ops_compact._read_counts(
+                    _delete_scan_fn(ctx.mesh, ctx.axis, cap)(
+                        counts, kdev, kc.data, kc.validity))
+                moved += per_shard.nbytes
+                if not per_shard.any():
+                    trace.count("ingest.host_bytes", moved)
+                    return self  # no row in the keys' range
+                sizes = _delete_sizes(per_shard)
+                ops_compact.update_size_hint(_delete_cap_hints, shape, sizes)
+            trace.count("ingest.host_bytes", moved)
+            outs, new_counts, total = _delete_fn(
+                ctx.mesh, ctx.axis, cap, sizes[0], ki)(
+                counts, kdev, tuple((c.data, c.validity)
+                                    for c in self.columns))
+            bound = self._counts_host if self._counts_host is not None \
+                else self._counts_bound
+            if bound is None:
+                bound = self.counts_host()
+            self._replace_contents(
+                [replace(c, data=d, validity=v)
+                 for c, (d, v) in zip(self.columns, outs)],
+                cap, new_counts, None)
+            self._counts_bound = bound
+            self._delete_seen = (shape, total)
+            return self
+
+    def _replace_contents(self, columns: List[DColumn], cap: int, counts,
+                          counts_host: Optional[np.ndarray]) -> None:
+        """Swap in a write's new leaves and bump the content epoch
+        (``counts_host`` None: the new counts are on the device only)."""
         if self._spill_entry is not None:
-            # the pooled host copy describes the PRE-append contents;
+            # the pooled host copy describes the PRE-write contents;
             # drop it rather than fault stale bytes back in later
             from ..spill.pool import get_pool
             get_pool().drop_entry(self._spill_entry.sig)
         self._spill_entry = None
         self._spill_sig = None
-        self._columns = grown._columns
-        self.cap = grown.cap
-        self._counts = grown._counts
+        self._columns = columns
+        self.cap = int(cap)
+        self._counts = counts
         self.pending_mask = None
         self.pending_cnts = None
-        self._counts_host = grown._counts_host
+        self._counts_host = (None if counts_host is None
+                             else np.asarray(counts_host, np.int32))
+        self._counts_bound = None
         self._epoch += 1
-        self._deltas[self._epoch] = other
-        for e in sorted(self._deltas):
-            if len(self._deltas) <= self._DELTA_KEEP:
-                break
-            del self._deltas[e]
-        return self
 
     def explain(self, plan=None, *, tables=None, validate: bool = False,
                 concrete=(), analyze: bool = False,
@@ -839,6 +1022,315 @@ def _head_fn(mesh, axis: str, cap: int, n: int, has_v):
     return named_jit(shard_map(kernel, mesh=mesh,
                                in_specs=(spec, spec), out_specs=(P(), P()),
                                check_vma=False))
+
+
+# ---------------------------------------------------------------------------
+# the write path: DTable.append / DTable.delete
+# ---------------------------------------------------------------------------
+
+def _host_delta(base: DTable, other) -> List[tuple]:
+    """``other``'s rows as host columns aligned with ``base``'s:
+    ``[(data, validity | None, dictionary | None), ...]``, a string
+    column as codes into the dictionary beside it."""
+    from .. import trace
+    if isinstance(other, dict):
+        if sorted(other) != sorted(base.column_names):
+            raise CylonError(Status(Code.Invalid,
+                f"append: columns {sorted(other)} != "
+                f"{sorted(base.column_names)}"))
+        out = []
+        for c in base.columns:
+            a = np.asarray(other[c.name])
+            coded = is_dictionary_encoded(c.dtype.type)
+            if coded and not np.issubdtype(a.dtype, np.integer):
+                raise CylonError(Status(Code.TypeError,
+                    f"append: string column {c.name!r} must be given as "
+                    "codes in the table's dictionary"))
+            out.append((a, None, c.dictionary if coded else None))
+        if len({len(a) for a, _, _ in out}) > 1:
+            raise CylonError(Status(Code.Invalid,
+                "append: columns of different lengths"))
+        return out
+    if isinstance(other, DTable):
+        base.verify_same_schema(other)
+        out = []
+        for c in other.to_table().columns:  # the delta's rows only
+            d = (c.host_data if c.host_data is not None
+                 else np.asarray(jax.device_get(c.data)))
+            v = c.host_validity
+            if v is None and c.validity is not None:
+                v = np.asarray(jax.device_get(c.validity))
+            trace.count("ingest.host_bytes",
+                        d.nbytes + (0 if v is None else v.nbytes))
+            out.append((d, v, c.dictionary))
+        return out
+    from ..table import host_columns_from_arrow
+    cols = host_columns_from_arrow(other)
+    if len(cols) != base.num_columns or any(
+            t != c.dtype.type for (_, t, *_), c in zip(cols, base.columns)):
+        raise CylonError(Status(Code.TypeError,
+            "append: the Arrow table's column types differ from the "
+            "table's"))
+    return [(npv, mask, dictionary)
+            for _, _, npv, mask, dictionary, _ in cols]
+
+
+def _stage(ctx, rows: List[Optional[np.ndarray]], take: np.ndarray):
+    """The delta's host columns laid out per shard (``take[p]`` rows at
+    the head of shard ``p``'s ``[dcap]`` block) and sent host→device in
+    one batched transfer, the per-shard row counts last.  Returns
+    ``(dcap, host blocks, device blocks)``."""
+    dcap = ops_compact.next_bucket(int(take.max()), minimum=8)
+    offs = np.concatenate([[0], np.cumsum(take)])
+    blocks = [None if a is None else _shard_blocks(a, take, offs, dcap)
+              for a in rows] + [take.astype(np.int32)]
+    return dcap, blocks, jax.device_put(blocks, ctx.sharding())
+
+
+def _shard_blocks(a: np.ndarray, take: np.ndarray, offs: np.ndarray,
+                  dcap: int) -> np.ndarray:
+    """Rows ``offs[p]:offs[p+1]`` of ``a`` at the head of shard ``p``'s
+    ``[dcap]`` block, zero padding after."""
+    out = np.zeros((len(take) * dcap,) + a.shape[1:], a.dtype)
+    for p, n in enumerate(take):
+        out[p * dcap:p * dcap + n] = a[offs[p]:offs[p + 1]]
+    return out
+
+
+def _water_fill(counts: np.ndarray, m: int) -> np.ndarray:
+    """Rows per shard for ``m`` new rows: the shards with the most
+    slack first, so the fullest shard ends as low as it can."""
+    level = counts.astype(np.int64)
+    lo, hi = int(level.min()), int(level.min()) + m
+    while lo < hi:  # the lowest level L with room for m rows below it
+        mid = (lo + hi) // 2
+        if np.maximum(mid - level, 0).sum() >= m:
+            hi = mid
+        else:
+            lo = mid + 1
+    take = np.maximum(lo - 1 - level, 0)
+    take[np.flatnonzero(level < lo)[:m - int(take.sum())]] += 1
+    return take
+
+
+def _merge_dictionary(base_dict: np.ndarray, delta_dict: np.ndarray,
+                      codes: np.ndarray):
+    """``(dictionary, base re-code map | None, delta codes in it)``: the
+    base's dictionary when it holds every delta value, else the sorted
+    union, with the map that re-codes the base onto it."""
+    codes = np.asarray(codes)
+    if delta_dict is base_dict:  # codes given in the table's dictionary
+        return base_dict, None, codes
+    if not len(delta_dict):  # an all-null delta column
+        return base_dict, None, np.zeros_like(codes, np.int32)
+    pos = np.searchsorted(base_dict, delta_dict)
+    if len(base_dict) and np.array_equal(
+            base_dict[np.minimum(pos, len(base_dict) - 1)], delta_dict):
+        return base_dict, None, pos.astype(np.int32)[codes]
+    merged = np.union1d(base_dict, delta_dict)
+    bmap = (np.searchsorted(merged, base_dict).astype(np.int32)
+            if len(base_dict) else None)
+    return merged, bmap, \
+        np.searchsorted(merged, delta_dict).astype(np.int32)[codes]
+
+
+@functools.partial(named_jit, name="dtable._insert_recode")
+def _recode(table: jax.Array, codes: jax.Array) -> jax.Array:
+    """A string column's codes onto a merged dictionary."""
+    return jnp.take(table, codes, axis=0)
+
+
+@kernel_factory
+def _insert_validity(mesh, axis: str, cap: int):
+    """Per shard: True on the first ``counts`` rows — the validity of a
+    column that had none, once a delta brings it nulls."""
+    from .._jax_compat import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def kernel(cnt):
+        return jnp.arange(cap, dtype=jnp.int32) < cnt[0]
+
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=P(axis),
+                               out_specs=P(axis)))
+
+
+@kernel_factory
+def _insert_fn(mesh, axis: str, cap: int, out_cap: int, dcap: int):
+    """Per shard: write the first ``take`` rows of the staged ``[dcap]``
+    delta block after the shard's ``counts`` rows, the block grown from
+    ``cap`` to ``out_cap`` rows (zero padding) when the delta overflows
+    it.  Returns the leaves and the new counts."""
+    from .._jax_compat import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def kernel(cnt, take, base, delta):
+        i = jnp.arange(dcap, dtype=jnp.int32)
+        tgt = jnp.where(i < take[0], cnt[0] + i, out_cap)
+        outs = []
+        for (bd, bv), (dd, dv) in zip(base, delta):
+            bd = ops_compact.pad_to(bd, out_cap).at[tgt].set(dd, mode="drop")
+            if bv is not None:
+                bv = ops_compact.pad_to(bv, out_cap).at[tgt].set(
+                    dv, mode="drop")
+            outs.append((bd, bv))
+        return tuple(outs), cnt + take
+
+    spec = P(axis)
+    return named_jit(shard_map(kernel, mesh=mesh, in_specs=(spec,) * 4,
+                               out_specs=(spec, spec)))
+
+
+def _delete_keys(keys, dtype: np.dtype) -> np.ndarray:
+    """``keys`` sorted and unique, as ``dtype``; a key no value of
+    ``dtype`` can equal is dropped."""
+    k = np.unique(np.asarray(jax.device_get(keys)).ravel())
+    if not k.size:
+        return k.astype(dtype)
+    if not np.issubdtype(k.dtype, np.integer):
+        raise CylonError(Status(Code.TypeError,
+            f"delete: keys must be integers, got {k.dtype}"))
+    info = np.iinfo(dtype)
+    return k[(k >= info.min) & (k <= info.max)].astype(dtype)
+
+
+def _delete_candidates(cap: int, n, keys, x, xv):
+    """The first ``n`` rows of a shard whose key lies in
+    ``[keys[0], keys[-1]]``."""
+    c = ((jnp.arange(cap, dtype=jnp.int32) < n)
+         & (x >= keys[0]) & (x <= keys[-1]))
+    return c if xv is None else c & xv
+
+
+# size-class hints of the delete's candidate count, per table shape
+_delete_cap_hints: dict = {}
+
+
+def _delete_sizes(per_shard: np.ndarray) -> tuple:
+    """``_delete_fn``'s size class for these candidate counts."""
+    return (ops_compact.next_bucket(max(int(per_shard.max(initial=0)), 1),
+                                    minimum=8),)
+
+
+def _note_delete_total(dt: DTable) -> Optional[np.ndarray]:
+    """Fold the candidate counts of ``dt``'s last delete into the size
+    hint of its table shape, once the device has them (a finished
+    program's output: reading it waits for nothing); returns them, or
+    None."""
+    if dt._delete_seen is None or not dt._delete_seen[1].is_ready():
+        return None
+    shape, total = dt._delete_seen
+    dt._delete_seen = None
+    per_shard = ops_compact._read_counts(total)
+    ops_compact.update_size_hint(_delete_cap_hints, shape,
+                                 _delete_sizes(per_shard))
+    return per_shard
+
+
+@kernel_factory
+def _delete_scan_fn(mesh, axis: str, cap: int):
+    """Per shard: the delete's candidate count (one pass over the key
+    column), which sizes the first ``_delete_fn`` of a table shape."""
+    from .._jax_compat import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def kernel(cnt, keys, x, xv):
+        return jnp.sum(_delete_candidates(cap, cnt[0], keys, x, xv),
+                       dtype=jnp.int32)[None]
+
+    spec = P(axis)
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec, P(), spec, spec),
+                               out_specs=spec))
+
+
+@kernel_factory
+def _delete_fn(mesh, axis: str, cap: int, ccap: int, key: int):
+    """Per shard: drop every row whose key (leaf ``key``) is in the
+    sorted ``keys``.  One pass (``_delete_pass``) handles the first
+    ``ccap`` candidates, all of them when the size class is right; while
+    candidates remain, further passes handle ``ccap`` more each, those
+    already found not to match marked so that each pass makes progress.
+    Returns the leaves, the new counts and the candidate count of the
+    first pass (which sizes the next delete)."""
+    from .._jax_compat import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def kernel(cnt, keys, leaves):
+        leaves, n, total, _ = _delete_pass(cap, ccap, key, cnt[0], keys,
+                                           leaves)
+
+        def again(state):
+            leaves, n, _, checked = state
+            leaves, n, seen, checked = _delete_pass(
+                cap, ccap, key, n, keys, leaves, checked)
+            return leaves, n, seen - jnp.minimum(seen, ccap), checked
+
+        leaves, n, _, _ = jax.lax.while_loop(
+            lambda state: state[2] > 0, again,
+            (leaves, n, total - jnp.minimum(total, ccap),
+             jnp.full(cap, n < 0)))  # none checked, per shard
+        return leaves, n[None], total[None]
+
+    spec = P(axis)
+    return named_jit(shard_map(kernel, mesh=mesh,
+                               in_specs=(spec, P(), spec),
+                               out_specs=(spec, spec, spec)))
+
+
+def _delete_pass(cap: int, ccap: int, key: int, n, keys, leaves,
+                 checked=None):
+    """Drop the rows whose key is in ``keys`` among the first ``ccap``
+    candidates of a shard's ``n`` rows (those not yet ``checked``, and
+    mark the rest of them checked).  With ``d`` rows dropped, the j-th
+    hole below the new end ``n - d`` takes the j-th surviving row of
+    ``[n - d, n)``, and the vacated rows are zeroed: O(candidates)
+    gathers and scatters past one scan of the key column.  Returns the
+    leaves, the new row count, the candidates seen and ``checked``."""
+    x, xv = leaves[key]
+    cand = _delete_candidates(cap, n, keys, x, xv)
+    if checked is not None:
+        cand = cand & ~checked
+    cs = jnp.cumsum(cand, dtype=jnp.int32)
+    t = jnp.arange(ccap, dtype=jnp.int32)
+    at = jnp.minimum(jnp.searchsorted(cs, t + 1), cap - 1) \
+        .astype(jnp.int32)  # the t-th candidate's row, ascending
+    ck = x[at]
+    live = t < cs[-1]
+    hit = live & (keys[jnp.minimum(
+        jnp.searchsorted(keys, ck), keys.shape[0] - 1)] == ck)
+    if checked is not None:
+        checked = checked.at[jnp.where(live & ~hit, at, cap)].set(
+            True, mode="drop")
+    d = jnp.sum(hit, dtype=jnp.int32)
+    holes = _pick(hit, at, cap)
+    end = n - d
+    tail = end + t
+    on_hole = holes[jnp.minimum(jnp.searchsorted(holes, tail),
+                                ccap - 1)] == tail
+    src = _pick((t < d) & ~on_hole, tail, cap)
+    dst = jnp.where(holes < end, holes, cap)
+    vacated = jnp.where(t < d, tail, cap)
+    leaves = tuple((_move_rows(dd, src, dst, vacated, cap),
+                    None if dv is None else
+                    _move_rows(dv, src, dst, vacated, cap))
+                   for dd, dv in leaves)
+    if checked is not None:
+        checked = _move_rows(checked, src, dst, vacated, cap)
+    return leaves, end, cs[-1], checked
+
+
+def _pick(mask, rows, fill: int):
+    """``rows[mask]`` in order, padded with ``fill`` to ``len(rows)``."""
+    n = rows.shape[0]
+    return jnp.append(rows, jnp.int32(fill))[
+        ops_compact.compact_indices(mask, n, fill=n)]
+
+
+def _move_rows(a, src, dst, vacated, cap: int):
+    """Row ``src[j]`` into row ``dst[j]``, then zeros at ``vacated``
+    (out-of-range targets are dropped)."""
+    a = a.at[dst].set(a[jnp.minimum(src, cap - 1)], mode="drop")
+    return a.at[vacated].set(jnp.zeros((), a.dtype), mode="drop")
 
 
 _ARENA_CAP = 256 << 20

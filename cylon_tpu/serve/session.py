@@ -881,25 +881,42 @@ class ServeSession:
 
     def ingest(self, name: str, delta, *, block: bool = True,
                timeout: Optional[float] = None) -> QueryHandle:
-        """Append ``delta`` to the session base table ``name`` THROUGH
-        the dispatcher (docs/serving.md "Materialized subplans" —
-        staleness model).  Routing writes through the queue serializes
-        them against query execution on the one dispatcher thread:
-        no query ever observes a half-applied append, every query
-        admitted after the ingest completes observes it (the bench's
-        measured visibility lag), and the table's content epoch bumps
-        exactly once per batch — which is what the view store folds
-        on.  Writes ride ``priority=1`` so load shedding never drops
-        data."""
-        if not isinstance(self._tables, dict) or name not in self._tables:
-            raise CylonError(Status(Code.Invalid,
-                f"serve: no session base table named {name!r} to "
-                "ingest into"))
-        base = self._tables[name]
+        """Append ``delta`` (a ``DTable``, an Arrow table or a dict of
+        numpy columns; ``DTable.append``) to the session base table
+        ``name`` THROUGH the dispatcher (docs/serving.md "Materialized
+        subplans" — staleness model).  Routing writes through the queue
+        serializes them against query execution on the one dispatcher
+        thread: no query ever observes a half-applied append, every
+        query admitted after the ingest completes observes it (the
+        bench's measured visibility lag), and the table's content epoch
+        bumps exactly once per batch — which is what the view store
+        folds on.  Writes ride ``priority=1`` so load shedding never
+        drops data."""
+        base = self._write_target(name, "ingest into")
         return self.submit(
             lambda base=base, delta=delta: base.append(delta),
             None, label=f"ingest:{name}", block=block, timeout=timeout,
             priority=1)
+
+    def delete(self, name: str, key_column, keys, *, block: bool = True,
+               timeout: Optional[float] = None) -> QueryHandle:
+        """Remove every row of the session base table ``name`` whose
+        ``key_column`` is in ``keys`` (``DTable.delete``), through the
+        dispatcher at ``priority=1`` exactly as :meth:`ingest`: no
+        query observes half a delete.  The content epoch bumps with no
+        foldable delta, so views over the table recompute."""
+        base = self._write_target(name, "delete from")
+        return self.submit(
+            lambda base=base, key_column=key_column, keys=keys:
+            base.delete(key_column, keys),
+            None, label=f"delete:{name}", block=block, timeout=timeout,
+            priority=1)
+
+    def _write_target(self, name: str, verb: str):
+        if not isinstance(self._tables, dict) or name not in self._tables:
+            raise CylonError(Status(Code.Invalid,
+                f"serve: no session base table named {name!r} to {verb}"))
+        return self._tables[name]
 
     def stats(self) -> Dict[str, Any]:
         """Session-level tallies + latency percentiles (independent of

@@ -33,9 +33,11 @@ This module is the structured equivalent:
     profiler's own trace on the device trace's clock.  ``hard_sync``'s
     blocking read is ``cylon.sync``; ``host_read`` marks the engine's
     blocking device→host reads (``cylon.host.read``) and ``planner``
-    the planner's extent (``cylon.plan.optimize``); ``annotate`` is the
-    bare span for host-side phases such as ``cylon.export``.  Off the
-    profiler an annotation costs about a microsecond.
+    the planner's extent (``cylon.plan.optimize``), ``ingest`` a write
+    to a resident table (``cylon.ingest.append`` / ``.delete``);
+    ``annotate`` is the bare span for host-side phases such as
+    ``cylon.export``.  Off the profiler an annotation costs about a
+    microsecond.
 
 Enable span recording with ``CYLON_TRACE=1`` in the environment or
 ``trace.enable()``.
@@ -61,7 +63,7 @@ __all__ = [
     "counters", "snapshot", "report", "bench_line", "export_chrome_trace",
     "profile", "hard_sync", "trace_context", "current_trace_id",
     "record_span", "finish_trace", "set_tail_budget", "tail_budget",
-    "tail_stats", "annotate", "host_read", "planner",
+    "tail_stats", "annotate", "host_read", "planner", "ingest",
 ]
 
 # the profiler-span namespace of the engine's own phases
@@ -344,26 +346,43 @@ def host_read() -> Iterator[None]:
 
 
 @contextlib.contextmanager
-def planner() -> Iterator[None]:
-    """The planner's extent (``plan.optimize``: capture, rewrite,
-    dispatch): the profiler span ``cylon.plan.optimize`` and, while
-    counters are enabled, ``plan.host_us`` — its host µs less the
-    ``host.read_us`` accrued inside it on this thread.  A nested
-    planner is charged to the outermost."""
-    with annotate("plan.optimize"):
-        if not counters_enabled() or getattr(_tls, "planning", False):
+def _host_phase(span: str, counter: str, flag: str) -> Iterator[None]:
+    """The profiler span ``cylon.<span>`` and, while counters are
+    enabled, ``counter``: the phase's host µs less the ``host.read_us``
+    accrued inside it on this thread.  A nested phase of the same
+    ``flag`` is charged to the outermost."""
+    with annotate(span):
+        if not counters_enabled() or getattr(_tls, flag, False):
             yield
             return
-        _tls.planning = True
+        setattr(_tls, flag, True)
         r0 = getattr(_tls, "read_us", 0)
         t0 = time.perf_counter()
         try:
             yield
         finally:
-            _tls.planning = False
+            setattr(_tls, flag, False)
             us = (time.perf_counter() - t0) * 1e6 \
                 - (getattr(_tls, "read_us", 0) - r0)
-            count("plan.host_us", max(0, round(us)))
+            count(counter, max(0, round(us)))
+
+
+def planner():
+    """The planner's extent (``plan.optimize``: capture, rewrite,
+    dispatch): the profiler span ``cylon.plan.optimize`` and, while
+    counters are enabled, ``plan.host_us`` — its host µs less the
+    ``host.read_us`` accrued inside it on this thread."""
+    return _host_phase("plan.optimize", "plan.host_us", "planning")
+
+
+def ingest(op: str):
+    """A write to a resident table (``DTable.append`` / ``delete``):
+    the profiler span ``cylon.ingest.<op>`` and, while counters are
+    enabled, ``ingest.host_us`` — its host µs less the ``host.read_us``
+    inside it.  The write path's other counters: ``ingest.rows_in``,
+    ``ingest.rows_deleted`` and ``ingest.host_bytes`` (bytes that cross
+    the host-device boundary, either way, inside the span)."""
+    return _host_phase("ingest." + op, "ingest.host_us", "ingesting")
 
 
 def count(name: str, n: int = 1) -> None:
